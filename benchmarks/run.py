@@ -99,18 +99,20 @@ def _model_of_one(fn) -> dict:
 
 def _sharded_row_subprocess(row_name):
     """Measure one sharded 1M-row kernel row in a child process with a
-    forced 4-device CPU backend.  Isolation is the honest methodology: the
-    XLA device-split flag divides the host's thread pool for *every* array
-    op in the process, so measuring the unsharded rows under it would tax
-    them with the sharded row's configuration (and the flag only takes
-    effect before jax initializes anyway).  ``row_name`` is matched
-    exactly (several sharded rows share a name prefix)."""
+    forced 4-device CPU backend (CPU parents only: the child is pinned to
+    the CPU, so it never contends for an accelerator the parent holds).
+    Isolation is the honest methodology: the XLA device-split flag divides
+    the host's thread pool for *every* array op in the process, so
+    measuring the unsharded rows under it would tax them with the sharded
+    row's configuration (and the flag only takes effect before jax
+    initializes anyway).  ``row_name`` is matched exactly (several sharded
+    rows share a name prefix)."""
     import subprocess
     import tempfile
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
-    env["_ARITPIM_SHARDED_BENCH_CHILD"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         " --xla_force_host_platform_device_count=4").strip()
     env["PYTHONPATH"] = os.path.join(repo, "src") + (
@@ -140,8 +142,13 @@ def _warm_start_probe(cache_dir: str) -> None:
     for all 8 programs, artifacts written); on a populated one it is the
     warm path (schedules + AOT executables deserialized, zero recompiles).
     Prints one JSON object on stdout; a blake2b digest of all outputs lets
-    the parent assert cold and warm runs are bit-identical."""
+    the parent assert cold and warm runs are bit-identical.  JAX's own
+    persistent compilation cache stays off in both probes, so "cold"
+    compiles everything and "warm" measures the artifact cache alone."""
     import hashlib
+
+    import jax
+    jax.config.update("jax_enable_compilation_cache", False)
 
     from repro import pim_ufunc as pim
     from repro.kernels import ops as kops
@@ -195,7 +202,9 @@ def _warm_start_rows(only: str = ""):
     persists the artifacts; the second (warm) restores them via
     ``ArtifactCache.warm()``.  Each child reports time-to-first-result for
     the whole suite; the warm row carries ``cold_start_us`` and the
-    tracked ``speedup_vs_cold`` (acceptance: >= 10x)."""
+    tracked ``speedup_vs_cold`` (acceptance: >= 10x).  The children need
+    the device, so :func:`collect_rows` runs them before this process
+    initialises JAX (one process per chip)."""
     import subprocess
     import tempfile
 
@@ -498,24 +507,23 @@ def _kernel_rows(only: str = ""):
             "chunk_rows": chunk, "n_devices": 1, **ex1}))
 
     def sharded_row(name, layout):
-        is_child = os.environ.get("_ARITPIM_SHARDED_BENCH_CHILD") == "1"
-        if len(jax.devices()) > 1:          # already multi-device: in-process
-            mesh = kops.row_mesh()
-            dt4, ex4 = bench_stream(mesh=mesh, layout=layout)
-            return (name, dt4 * 1e6, {
-                "rows_per_s": _rate(nm, dt4), "backend": "ref",
-                "levelized": 1, "chunk_rows": chunk, "layout": layout,
-                "n_devices": int(mesh.devices.size), **ex4})
-        if is_child:
-            # the device-split flag did not take (e.g. a non-CPU backend
-            # ignores it): record the degenerate single-device measurement
-            # rather than recursing into another identical child
-            dt4, ex4 = bench_stream(mesh=None, layout=layout)
-            return (name, dt4 * 1e6, {
-                "rows_per_s": _rate(nm, dt4), "backend": "ref",
-                "levelized": 1, "chunk_rows": chunk, "layout": layout,
-                "n_devices": 1, **ex4})
-        return _sharded_row_subprocess(name)
+        # a one-device CPU parent measures in a forced 4-device CPU child;
+        # every other backend measures in-process over its real devices
+        n_dev = len(jax.devices())
+        if n_dev == 1 and jax.default_backend() == "cpu" and \
+                "--xla_force_host_platform_device_count" not in \
+                os.environ.get("XLA_FLAGS", ""):
+            return _sharded_row_subprocess(name)
+        if n_dev < 4:
+            raise RuntimeError(
+                f"{name} shards over 4 devices; this "
+                f"{jax.default_backend()} backend has {n_dev}")
+        mesh = kops.row_mesh()
+        dt4, ex4 = bench_stream(mesh=mesh, layout=layout)
+        return (name, dt4 * 1e6, {
+            "rows_per_s": _rate(nm, dt4), "backend": "ref",
+            "levelized": 1, "chunk_rows": chunk, "layout": layout,
+            "n_devices": int(mesh.devices.size), **ex4})
 
     if want_row("kernel/fp16_add_1M_rows_sharded"):
         rows.append(sharded_row("kernel/fp16_add_1M_rows_sharded",
@@ -640,10 +648,12 @@ def _serve_rows(only: str = ""):
 
 def collect_rows(only: str = "") -> list:
     """All benchmark rows as (name, us_per_call, derived-dict) tuples."""
-    rows = []
-
     def want(prefix):
         return not only or prefix.startswith(only) or only.startswith(prefix)
+
+    # first: the warm-start children need the device, which this process
+    # holds from its first JAX computation on
+    rows = _warm_start_rows(only) if want("kernel") else []
 
     if want("cycles"):
         from . import cycles
@@ -696,7 +706,6 @@ def collect_rows(only: str = "") -> list:
 
     if want("kernel"):
         rows.extend(_kernel_rows(only))
-        rows.extend(_warm_start_rows(only))
     if want("serve"):
         rows.extend(_serve_rows(only))
     if only:
@@ -783,6 +792,8 @@ def main(argv=None) -> None:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "") +
             f" --xla_force_host_platform_device_count={args.devices}").strip()
+    from repro.runtime import compile_cache
+    compile_cache.enable()
 
     rows = collect_rows(args.only)
     print("name,us_per_call,derived")

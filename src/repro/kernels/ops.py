@@ -38,8 +38,7 @@ from typing import Callable, Dict, Iterable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.gates import LevelSchedule, levelize
 from ..runtime import telemetry
@@ -50,9 +49,10 @@ from . import slots as kslots
 from .plan import (BACKENDS, DEFAULT_LAYOUT, DEFAULT_PLAN, DEFAULT_SCHEDULE,
                    LAYOUTS, ROWS32, ROWS64, SCHEDULES, TILE_W, Backend,
                    ExecPlan, WordLayout, as_plan)
-from .pim_exec import (check_words, make_slots_static, pim_exec_level_fused,
-                       pim_exec_level_padded_io, pim_exec_padded,
-                       pim_exec_slots_fused, pim_exec_slots_io)
+from .pim_exec import (check_words, interpret_mode, make_slots_static,
+                       pim_exec_level_fused, pim_exec_level_padded_io,
+                       pim_exec_padded, pim_exec_slots_fused,
+                       pim_exec_slots_io)
 from .ref import (pim_exec_ref, pim_exec_ref_level_fused,
                   pim_exec_ref_level_io)
 from .slots import (as_run, pim_exec_ref_slots_fused, pim_exec_ref_slots_io)
@@ -133,8 +133,9 @@ def _serial_model(program) -> "telemetry.ModeledCost":
 #: periodic stats lines derive the cache hit rate from.  The disk tier
 #: (``runtime.artifact_cache``) adds ``disk_hits``/``disk_misses``/
 #: ``disk_writes``/``disk_errors``/``disk_evictions`` to the same group,
-#: and ``levelized`` below counts *fresh* levelizations -- the signal a
-#: warm-started replica drives to zero.
+#: ``levelized`` below counts *fresh* levelizations -- the signal a
+#: warm-started replica drives to zero -- and ``aot_failed`` counts the
+#: AOT-tier fallbacks to plain jit in :func:`_aot_call`.
 _CACHE = telemetry.REGISTRY.group("pim.cache")
 
 # --------------------------------------------------------------------------
@@ -160,9 +161,24 @@ _provenance: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
 def set_artifact_cache(cache) -> None:
     """Install (or, with None, remove) the process-wide on-disk artifact
-    cache consulted by the compiled-program machinery."""
+    cache consulted by the compiled-program machinery.
+
+    Schedules the in-memory LRU already holds were levelized before this
+    tier existed and would never pass through it (later calls hit memory),
+    so installing a cache writes them through at once; otherwise a cache
+    directory installed mid-process silently lacks every program the
+    process had already run."""
     global _artifacts
     _artifacts = cache
+    if cache is None:
+        return
+    programs = {key: prog for prog, key in list(_key_memo.items())}
+    for (content, ck), entry in list(_compiled.items()):
+        prog = programs.get(content)
+        for alloc, sched in entry.scheds.items():
+            cache.store_schedule(
+                content, ck, alloc, sched,
+                provenance=None if prog is None else provenance_of(prog))
 
 
 def artifact_cache():
@@ -772,25 +788,46 @@ _shard_cache: "collections.OrderedDict[tuple, Callable]" = \
     collections.OrderedDict()
 
 
-def _sharded_exec(fn, mesh: Mesh, check_rep: bool, data_rank: int = 2,
+def _sharded_exec(fn, mesh: Mesh, check_vma: bool, data_rank: int = 2,
                   **static) -> Callable:
-    """``jax.jit(shard_map(fn))`` over the rank-matched specs, cached per
-    (executor, mesh, statics) so each chunk shape compiles once.  Pallas
-    calls have no replication rule, hence ``check_rep=False`` there."""
-    key = (fn, mesh, check_rep, data_rank, tuple(sorted(static.items())))
+    """``jax.jit(jax.shard_map(fn))`` over the rank-matched specs, cached
+    per (executor, mesh, statics) so each chunk shape compiles once.
+    Pallas calls have no varying-axes rule, hence ``check_vma=False``
+    there."""
+    key = (fn, mesh, check_vma, data_rank, tuple(sorted(static.items())))
     wrapped = _shard_cache.get(key)
     if wrapped is None:
         inner = functools.partial(fn, **static)
         in_specs, out_spec = _shard_specs(data_rank)
-        wrapped = jax.jit(shard_map(
+        wrapped = jax.jit(jax.shard_map(
             inner, mesh=mesh, in_specs=in_specs,
-            out_specs=out_spec, check_rep=check_rep))
+            out_specs=out_spec, check_vma=check_vma))
         _shard_cache[key] = wrapped
         while len(_shard_cache) > _SHARD_CACHE_CAP:
             _shard_cache.popitem(last=False)
     else:
         _shard_cache.move_to_end(key)
     return wrapped
+
+
+def _place_rows(block: np.ndarray, mesh: Mesh):
+    """Put a host data block straight onto the row mesh, split along its
+    trailing word/row axis: each device receives only its own shard
+    (``jnp.asarray`` would land the whole operand on one device first)."""
+    return jax.device_put(block,
+                          NamedSharding(mesh, _shard_specs(block.ndim)[1]))
+
+
+def _refuse_interpret_only(kernel: str) -> None:
+    """On a TPU, refuse a plan whose Pallas kernel runs only in interpret
+    mode (its operand read is a vector gather that Mosaic does not lower):
+    such a plan raises instead of running interpreted without saying so."""
+    if not interpret_mode():
+        raise ValueError(
+            f"backend='pallas' reaches the {kernel} kernel, which runs only "
+            "in Pallas interpret mode, never on a TPU; use "
+            "schedule='slots-static' (unsharded, ports of <= 32 cells, "
+            "inputs from cell 0), levelized=False, or backend='ref'")
 
 
 # --------------------------------------------------------------------------
@@ -1155,8 +1192,9 @@ def _aot_call(comp, program, plan: ExecPlan, fn, args: tuple, static: dict):
     ``warm()``-ed replica) deserialize it in milliseconds and skip tracing
     entirely.  Any failure -- XLA refusing to serialize, version skew, a
     deserialized executable rejecting the operands -- permanently marks
-    the signature failed for this entry and falls back to the plain jit
-    path, so AOT is strictly an optimization, never a correctness risk.
+    the signature failed for this entry, counts ``pim.cache.aot_failed``
+    and falls back to the plain jit path, so AOT is strictly an
+    optimization, never a correctness risk, and never a silent one.
     Mesh-sharded and trace-time-static paths never come through here."""
     if _artifacts is None or not getattr(_artifacts, "aot", False):
         return fn(*args, **static)
@@ -1171,6 +1209,7 @@ def _aot_call(comp, program, plan: ExecPlan, fn, args: tuple, static: dict):
         except Exception:
             del comp.aot[memo]
             comp.aot_failed.add(memo)
+            _CACHE.add("aot_failed")
             return fn(*args, **static)
     if memo in comp.aot_failed:
         return fn(*args, **static)
@@ -1184,6 +1223,7 @@ def _aot_call(comp, program, plan: ExecPlan, fn, args: tuple, static: dict):
         out = loaded(*args)
     except Exception:
         comp.aot_failed.add(memo)
+        _CACHE.add("aot_failed")
         return fn(*args, **static)
     comp.aot[memo] = loaded
     return out
@@ -1262,6 +1302,9 @@ def _dispatch_levelized(program, inputs: Dict[str, np.ndarray], n_rows: int,
                                          r.in_widths, r.out_widths)
             outs = run(jnp.asarray(in_vals))
         else:
+            if is_pallas:
+                _refuse_interpret_only("slot-scan" if r.kind != "dense"
+                                       else "dense gather")
             if r.kind != "dense":
                 fn = (pim_exec_slots_fused if is_pallas
                       else pim_exec_ref_slots_fused)
@@ -1281,7 +1324,7 @@ def _dispatch_levelized(program, inputs: Dict[str, np.ndarray], n_rows: int,
                                   r.lb, r.lo, r.out_idx), static)
             else:
                 outs = _sharded_exec(fn, mesh, not is_pallas, 2, **static)(
-                    jnp.asarray(in_vals), r.in_idx, r.la, r.lb, r.lo,
+                    _place_rows(in_vals, mesh), r.in_idx, r.la, r.lb, r.lo,
                     r.out_idx)
 
         # verified-under-fault plans emit the XOR check plane *on the
@@ -1322,8 +1365,9 @@ def _dispatch_levelized(program, inputs: Dict[str, np.ndarray], n_rows: int,
                                     r.in_widths, r.out_widths)
         sub = run(jnp.asarray(in_rows))
     else:
-        # (slots-static + pallas has no wide-port static kernel; the scan
-        # slot executor is the closest hardware shape)
+        if is_pallas:       # no wide-port or packed-domain static kernel
+            _refuse_interpret_only("slot-scan" if r.kind != "dense"
+                                   else "dense gather")
         if r.kind != "dense":
             exec_fn = (pim_exec_slots_io if is_pallas
                        else pim_exec_ref_slots_io)
@@ -1341,7 +1385,8 @@ def _dispatch_levelized(program, inputs: Dict[str, np.ndarray], n_rows: int,
         else:
             sub = _sharded_exec(exec_fn, mesh, not is_pallas,
                                 in_rows.ndim, **static)(
-                jnp.asarray(in_rows), r.in_idx, r.la, r.lb, r.lo, r.out_idx)
+                _place_rows(in_rows, mesh), r.in_idx, r.la, r.lb, r.lo,
+                r.out_idx)
 
     # on-device check plane for the packed/padded-io path too: the fold
     # runs over the cell axis (-2) of the packed output block
@@ -1368,7 +1413,7 @@ def run_program(program, inputs: Dict[str, np.ndarray], n_rows: int,
     """Element-parallel execution of a gate program over ``n_rows`` rows.
 
     ``plan`` is an :class:`ExecPlan` -- or, for convenience, a backend
-    name ('pallas' interpret-mode kernels, 'ref' jnp oracle, 'numpy' the
+    name ('pallas' kernels, 'ref' jnp oracle, 'numpy' the
     cycle-accurate simulator's packed executor); the keyword strings
     (``backend=``/``schedule=``/``layout=``/``mesh=``) build a plan at
     this boundary.  'pallas' and 'ref' consume the levelized schedule by

@@ -23,8 +23,8 @@ Two executors (DESIGN.md §5):
     a ``fori_loop`` over *levels*; each iteration gathers the level's
     operand rows, NORs them as one (width, TILE_W) block and scatters the
     results.  The gather/scatter use vector indices, which Mosaic does not
-    lower for uint32 row gathers, so this legacy path requires
-    ``interpret=True``.
+    lower for uint32 row gathers, so this legacy path runs only in
+    interpret mode.
 
 Slot-schedule kernels (DESIGN.md §9), consuming ``alloc="slots"``
 schedules from ``core.gates.levelize``:
@@ -43,9 +43,14 @@ schedules from ``core.gates.levelize``:
     time, every read is a ``lax.slice`` at a Python-constant offset (merged
     into maximal runs), every band is an SSA value, and the output block is
     a static concatenation -- **zero dynamic indexing**, so the kernel body
-    is Mosaic-lowerable on hardware.  ``interpret=True`` stays the CPU test
-    default; on CPU the unrolled form trades the loop for per-op interpret
-    overhead, which is why the scan kernel above is the CPU benchmark path.
+    is Mosaic-lowerable on hardware.  On CPU the unrolled form trades the
+    loop for per-op interpret overhead, which is why the scan kernel above
+    is the CPU benchmark path.
+
+Every entry point takes ``interpret=None``: :func:`interpret_mode` resolves
+it from the platform (compiled through Mosaic on a TPU, interpreted
+elsewhere).  ``kernels.ops`` refuses, on a TPU, every plan that would reach
+one of the interpret-only kernels above.
 """
 
 from __future__ import annotations
@@ -64,6 +69,16 @@ from .slots import (SLOT_UNROLL, at_cells, band_slice, band_update,
                     read_concat, static_plan, take_cells, unpack_values)
 
 _FULL = 0xFFFFFFFF
+
+
+def interpret_mode(interpret=None) -> bool:
+    """Whether a ``pallas_call`` runs in interpret mode: never on a TPU,
+    always on every other backend (which has no Mosaic compiler).  The
+    single place the mode is decided; an explicit bool wins (tests compile
+    for a described TPU from a CPU process with ``interpret=False``)."""
+    if interpret is not None:
+        return bool(interpret)
+    return jax.default_backend() != "tpu"
 
 
 def _check_state_shape(where: str, state, n_cells: int) -> None:
@@ -97,12 +112,12 @@ def _pim_kernel(ops_ref, a_ref, b_ref, o_ref, state_ref, out_ref):
 
     def body(i, carry):
         op = ops_ref[i]
-        av = pl.load(out_ref, (pl.ds(a_ref[i], 1), slice(None)))
-        bv = pl.load(out_ref, (pl.ds(b_ref[i], 1), slice(None)))
+        av = out_ref[pl.ds(a_ref[i], 1), :]
+        bv = out_ref[pl.ds(b_ref[i], 1), :]
         nor = ~(av | bv)                      # NOT == NOR with b == a
         init = jnp.where(op == 1, jnp.uint32(_FULL), jnp.uint32(0))
         res = jnp.where(op >= 2, nor, jnp.broadcast_to(init, nor.shape))
-        pl.store(out_ref, (pl.ds(o_ref[i], 1), slice(None)), res)
+        out_ref[pl.ds(o_ref[i], 1), :] = res
         return carry
 
     jax.lax.fori_loop(0, n, body, 0)
@@ -111,7 +126,7 @@ def _pim_kernel(ops_ref, a_ref, b_ref, o_ref, state_ref, out_ref):
 @functools.partial(jax.jit,
                    static_argnames=("n_cells", "interpret"),
                    donate_argnums=(0,))
-def pim_exec_padded(state, ops, a, b, o, *, n_cells, interpret=True):
+def pim_exec_padded(state, ops, a, b, o, *, n_cells, interpret=None):
     """Run a lowered NOR program over ``state`` (uint32[n_cells, n_words]),
     n_words a multiple of TILE_W.  Returns the final state.  ``state`` is
     donated (single-use staging buffer on the gate-serial path)."""
@@ -127,7 +142,7 @@ def pim_exec_padded(state, ops, a, b, o, *, n_cells, interpret=True):
             out_specs=pl.BlockSpec((n_cells, TILE_W), lambda i, *_: (0, i)),
         ),
         out_shape=jax.ShapeDtypeStruct(state.shape, jnp.uint32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(ops, a, b, o, state)
 
 
@@ -154,7 +169,7 @@ def _pim_level_gather_kernel(la_ref, lb_ref, lo_ref, state_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("n_cells", "interpret"),
                    donate_argnums=(0,))
 def pim_exec_level_padded(state, la, lb, lo, out_idx=None, *, n_cells,
-                          interpret=True):
+                          interpret=None):
     """Run a levelized NOR schedule over ``state`` (uint32[n_cells,
     n_words] or the planes-leading rows64 form), n_words a multiple of
     TILE_W.  ``la``/``lb``/``lo`` are the LevelSchedule's dense
@@ -177,7 +192,7 @@ def pim_exec_level_padded(state, la, lb, lo, out_idx=None, *, n_cells,
             out_specs=pl.BlockSpec(block, index_map),
         ),
         out_shape=jax.ShapeDtypeStruct(state.shape, jnp.uint32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(la, lb, lo, state)
     return final if out_idx is None else take_cells(final, out_idx)
 
@@ -187,7 +202,7 @@ def pim_exec_level_padded(state, la, lb, lo, out_idx=None, *, n_cells,
     "planes"))
 def pim_exec_level_fused(in_vals, in_idx, la, lb, lo, out_idx, *,
                          n_cells, one_cell, in_widths, out_widths,
-                         interpret=True, planes=1):
+                         interpret=None, planes=1):
     """Fully fused levelized Pallas executor (ports of <= 32 cells): the
     row-major <-> column-major bit transposes run on device around the
     kernel, so only (n_ports, n_rows) uint32 values cross the boundary.
@@ -204,7 +219,7 @@ def pim_exec_level_fused(in_vals, in_idx, la, lb, lo, out_idx, *,
 @functools.partial(jax.jit,
                    static_argnames=("n_cells", "one_cell", "interpret"))
 def pim_exec_level_padded_io(in_rows, in_idx, la, lb, lo, out_idx, *,
-                             n_cells, one_cell=None, interpret=True):
+                             n_cells, one_cell=None, interpret=None):
     """Levelized Pallas executor with on-device state assembly: ships in
     only the input port rows (uint32[k_in, n_words], planes-leading under
     rows64), materializes the zero state and the folded INIT1 constant
@@ -276,7 +291,7 @@ def _slots_call(kernel, k_out, n_words, interpret, la, lb, lo,
         kernel,
         out_shape=jax.ShapeDtypeStruct(
             plane_shape(planes, max(k_out, 1), n_words), jnp.uint32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(la, lb, lo, in_rows)
 
 
@@ -286,7 +301,7 @@ def _slots_call(kernel, k_out, n_words, interpret, la, lb, lo,
 def pim_exec_slots_fused(in_vals, in_idx, la, lb, lo, out_idx, *,
                          n_cells, one_cell, in_widths, out_widths,
                          in_base, out_base, unroll=SLOT_UNROLL,
-                         interpret=True, planes=1):
+                         interpret=None, planes=1):
     """Fused slot executor, Pallas backend: butterfly bit transposes wrap a
     single scan-form kernel; only (n_ports, n_rows) uint32 values cross the
     host/device boundary.  Requires the slot layout's contiguous input and
@@ -311,7 +326,7 @@ def pim_exec_slots_fused(in_vals, in_idx, la, lb, lo, out_idx, *,
     "interpret"))
 def pim_exec_slots_io(in_rows, in_idx, la, lb, lo, out_idx, *,
                       n_cells, one_cell, k_out, in_base, out_base,
-                      unroll=SLOT_UNROLL, interpret=True):
+                      unroll=SLOT_UNROLL, interpret=None):
     """Slot executor over pre-packed port rows, Pallas backend (arbitrary
     port widths; the word layout is inferred from the input rank)."""
     planes = 1 if in_rows.ndim == 2 else in_rows.shape[0]
@@ -358,7 +373,7 @@ def _pim_level_kernel(sched, in_widths, out_names):
 
 
 def make_slots_static(sched, in_widths, out_widths, out_names,
-                      interpret=True, planes=1):
+                      interpret=None, planes=1):
     """Hardware-legal levelized Pallas executor factory: returns a jitted
     ``run(in_vals) -> out_vals`` wrapping one ``pallas_call`` whose body is
     the fully static-slice form of ``sched`` (see
@@ -392,7 +407,7 @@ def make_slots_static(sched, in_widths, out_widths, out_names,
             out_specs=block(k_out),
             out_shape=jax.ShapeDtypeStruct(
                 plane_shape(planes, max(k_out, 1), n_words), jnp.uint32),
-            interpret=interpret,
+            interpret=interpret_mode(interpret),
         )(packed)
         return unpack_values(sub[..., :k_out, :], out_widths, planes)
 

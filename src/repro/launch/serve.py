@@ -380,6 +380,7 @@ def serve_pim_batched(inp=None, outp=None, *, window_ms: float = 2.0,
                 "disk_misses": int(reg.counter("pim.cache.disk_misses")),
                 "disk_writes": int(reg.counter("pim.cache.disk_writes")),
                 "disk_errors": int(reg.counter("pim.cache.disk_errors")),
+                "aot_failed": int(reg.counter("pim.cache.aot_failed")),
                 "disk_evictions":
                     int(reg.counter("pim.cache.disk_evictions"))}
 
@@ -740,6 +741,8 @@ def main(argv=None):
                          "executor word axis) -- lands in every request's "
                          "ExecPlan")
     args = ap.parse_args(argv)
+    from ..runtime import compile_cache
+    compile_cache.enable()
 
     import contextlib
     ctx = contextlib.nullcontext()

@@ -81,6 +81,42 @@ def test_disk_roundtrip_bit_exact_all_layouts_schedules(cache):
         assert np.array_equal(outs[i + 1], (fx * fy).astype(np.float16))
 
 
+def test_install_writes_through_schedules_already_in_memory(tmp_path):
+    """A cache installed after a program already ran (its schedule only in
+    the in-memory LRU) still receives that schedule, so a process that
+    drops its memory -- or a replica warming from the directory -- finds
+    it on disk instead of levelizing again."""
+    rng = np.random.default_rng(4)
+    x = rng.integers(0, 1 << 8, 64).astype(np.uint8)
+    y = rng.integers(0, 1 << 8, 64).astype(np.uint8)
+    out = pim.sub(x, y, width=8)                 # no disk tier yet
+    kops.set_artifact_cache(ArtifactCache(tmp_path / "late"))
+    try:
+        kops.clear_compiled_cache()
+        lev0 = _c("levelized")
+        assert np.array_equal(pim.sub(x, y, width=8), out)
+        assert _c("levelized") == lev0
+    finally:
+        kops.set_artifact_cache(None)
+        kops.clear_compiled_cache()
+
+
+def test_aot_fallback_is_counted(cache, monkeypatch):
+    """An AOT-tier failure falls back to plain jit -- still bit-exact --
+    and counts ``pim.cache.aot_failed`` instead of passing silently."""
+    def broken(*a, **k):
+        raise RuntimeError("unreadable executable")
+
+    monkeypatch.setattr(cache, "load_executable", broken)
+    kops.clear_compiled_cache()       # no executable already in memory
+    rng = np.random.default_rng(5)
+    x = rng.integers(0, 1 << 8, 64).astype(np.uint8)
+    y = rng.integers(0, 1 << 8, 64).astype(np.uint8)
+    failed0 = _c("aot_failed")
+    assert np.array_equal(pim.add(x, y, width=8), x.astype(np.uint64) + y)
+    assert _c("aot_failed") > failed0
+
+
 def test_corruption_recomputes_and_heals(cache):
     """A byte flipped anywhere in an artifact fails the checksum: the load
     counts ``disk_errors``, unlinks the bad file, recomputes the correct

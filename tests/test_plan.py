@@ -231,6 +231,46 @@ def test_rows64_parity_pallas():
                     (schedule, layout, port)
 
 
+def test_pallas_interpret_mode_follows_platform():
+    """Interpret mode is decided from the platform (this CPU process has
+    no Mosaic compiler); an explicit bool wins."""
+    from repro.kernels import pim_exec
+    assert pim_exec.interpret_mode() is True
+    assert pim_exec.interpret_mode(False) is False
+    assert pim_exec.interpret_mode(True) is True
+
+
+@pytest.mark.parametrize("kind,op,param,schedule,levelized,lowers", [
+    ("fp-serial", "add", "fp16", "slots-static", True, True),
+    ("int-serial", "add", 16, "slots", False, True),     # gate-serial
+    ("fp-serial", "add", "fp16", "slots", True, False),  # slot scan
+    ("fp-serial", "add", "fp16", "dense", True, False),  # dense gather
+    ("int-serial", "mul", 32, "slots-static", True, False),  # wide ports
+])
+def test_pallas_plans_on_tpu_lower_or_raise(monkeypatch, kind, op, param,
+                                            schedule, levelized, lowers):
+    """With interpret mode off (as on a TPU), a Pallas plan reaches a
+    Mosaic-lowerable kernel or raises naming the lowerable schedule; it
+    never runs an interpret-only kernel.  Only the dispatcher's view of
+    the platform is switched: the kernels that may run still run
+    interpreted here, and must stay bit-exact."""
+    monkeypatch.setattr(kops, "interpret_mode", lambda interpret=None: False)
+    rng = np.random.default_rng(5)
+    prog = program_for(kind, op, param)
+    n = 96
+    ins = {name: rng.integers(0, 1 << 8, n).astype(np.uint64)
+           for name in prog.in_ports}
+    plan = kops.make_plan(backend="pallas", schedule=schedule)
+    if not lowers:
+        with pytest.raises(ValueError, match="slots-static"):
+            kops.run_program(prog, ins, n, plan)
+        return
+    got = kops.run_program(prog, ins, n, plan, levelized=levelized)
+    want = kops.run_program(prog, ins, n, "numpy")
+    for port in want:
+        assert np.array_equal(got[port], want[port]), port
+
+
 def test_rows64_ufunc_and_streaming_parity():
     rng = np.random.default_rng(12)
     n = 3000

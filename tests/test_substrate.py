@@ -137,7 +137,6 @@ _COMPRESSION_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np, json
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.optim.compression import compressed_psum, init_residuals
 
     mesh = jax.make_mesh((8,), ("data",))
@@ -146,9 +145,9 @@ _COMPRESSION_SCRIPT = textwrap.dedent("""
 
     @jax.jit
     def agg(g, r):
-        fn = shard_map(lambda gg, rr: compressed_psum(gg, rr, "data"),
-                       mesh=mesh, in_specs=(P("data"), P("data")),
-                       out_specs=(P("data"), P("data")))
+        fn = jax.shard_map(lambda gg, rr: compressed_psum(gg, rr, "data"),
+                           mesh=mesh, in_specs=(P("data"), P("data")),
+                           out_specs=(P("data"), P("data")))
         return fn(g, r)
 
     red, r2 = agg(g, r)
