@@ -138,6 +138,20 @@ def _serial_model(program) -> "telemetry.ModeledCost":
 #: AOT-tier fallbacks to plain jit in :func:`_aot_call`.
 _CACHE = telemetry.REGISTRY.group("pim.cache")
 
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _count_compile(event: str, secs: float, **_kw) -> None:
+    """``pim.jit.compiles`` / ``pim.jit.compile_s``: every XLA backend
+    compile in the process (a persistent-cache hit compiles nothing), so a
+    compile inside a timed window shows in that window's counter change."""
+    if event == _BACKEND_COMPILE_EVENT:
+        telemetry.REGISTRY.add_many({"pim.jit.compiles": 1,
+                                     "pim.jit.compile_s": secs})
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_compile)
+
 # --------------------------------------------------------------------------
 # optional on-disk artifact tier (DESIGN.md §16)
 # --------------------------------------------------------------------------
@@ -1229,12 +1243,18 @@ def _aot_call(comp, program, plan: ExecPlan, fn, args: tuple, static: dict):
     return out
 
 
+def _to_device(block: np.ndarray, mesh: Optional[Mesh]):
+    """A host data block on the device, or split over the row mesh."""
+    return jnp.asarray(block) if mesh is None else _place_rows(block, mesh)
+
+
 def _dispatch_levelized(program, inputs: Dict[str, np.ndarray], n_rows: int,
                         plan: ExecPlan,
                         pad_rows: Optional[int] = None, *,
                         fctx: Optional[_FaultCtx] = None,
                         packed_in: Optional[np.ndarray] = None,
-                        packed_out: bool = False) -> Callable:
+                        packed_out: bool = False,
+                        chunk: int = 0) -> Callable:
     """Pack ``inputs`` and dispatch one levelized execution under ``plan``;
     returns a zero-arg ``finalize`` that blocks on the device result and
     unpacks it.
@@ -1251,6 +1271,11 @@ def _dispatch_levelized(program, inputs: Dict[str, np.ndarray], n_rows: int,
     (``inputs`` then only names the ports), and ``packed_out`` makes
     ``finalize`` return the raw packed output block (out-ports stacked in
     ``output_names`` order) instead of unpacked row values.
+
+    Each step runs in a ``pim.dispatch.*`` tracer span (DESIGN.md §15)
+    whose args are the rows and ``chunk``, the chunk's index in its call:
+    ``pack``, ``h2d`` and ``launch`` here; ``wait``, ``d2h`` and
+    ``unpack`` in ``finalize``.
     """
     comp = compiled(program, plan)
     in_names = sorted(inputs)
@@ -1260,19 +1285,7 @@ def _dispatch_levelized(program, inputs: Dict[str, np.ndarray], n_rows: int,
     # the telemetry cost is a handful of dict ops, independent of rows
     # and schedule size, so the tracked-kernel overhead stays <2%
     telemetry.record_dispatch(n_rows, r.model)
-    tracer = telemetry.TRACER
-    t_disp = time.perf_counter() if tracer.enabled else 0.0
-
-    def _traced(fin: Callable) -> Callable:
-        if not tracer.enabled:
-            return fin
-        def wrapped():
-            out = fin()
-            tracer.event("exec", t_disp, time.perf_counter(),
-                         cat="pim.exec", rows=n_rows,
-                         levels=int(r.sched.n_levels), kind=r.kind)
-            return out
-        return wrapped
+    span = telemetry.TRACER.span
 
     layout, backend, mesh = plan.layout, plan.backend, plan.mesh
     planes = layout.planes
@@ -1288,44 +1301,49 @@ def _dispatch_levelized(program, inputs: Dict[str, np.ndarray], n_rows: int,
     if use_fused:
         # fused fast path: the bit transposes run inside the executor's
         # XLA program; only (n_ports, n_rows) uint32 cross the boundary
-        pad_rows_total = n_words * 32 * planes
-        in_vals = np.empty((len(vals), pad_rows_total), np.uint32)
-        for p, v in enumerate(vals):
-            in_vals[p, :len(v)] = v           # same-kind cast in place
-            in_vals[p, len(v):] = 0           # only the ragged tail zeroed
-        if r.use_static and not is_pallas:
-            run = comp.get_static_chain(program, plan, in_names, True,
-                                        r.in_widths, r.out_widths)
-            outs = run(jnp.asarray(in_vals))
-        elif r.use_static and r.in_base == 0:
-            run = comp.get_static_pallas(program, plan, in_names,
-                                         r.in_widths, r.out_widths)
-            outs = run(jnp.asarray(in_vals))
-        else:
-            if is_pallas:
-                _refuse_interpret_only("slot-scan" if r.kind != "dense"
-                                       else "dense gather")
-            if r.kind != "dense":
-                fn = (pim_exec_slots_fused if is_pallas
-                      else pim_exec_ref_slots_fused)
-                static = dict(n_cells=r.sched.n_cells, one_cell=r.one_cell,
-                              in_widths=r.in_widths, out_widths=r.out_widths,
-                              in_base=r.in_base, out_base=r.out_base,
-                              planes=planes)
+        with span("pim.dispatch.pack", rows=n_rows, chunk=chunk):
+            pad_rows_total = n_words * 32 * planes
+            in_vals = np.empty((len(vals), pad_rows_total), np.uint32)
+            for p, v in enumerate(vals):
+                in_vals[p, :len(v)] = v       # same-kind cast in place
+                in_vals[p, len(v):] = 0       # only the ragged tail zeroed
+        with span("pim.dispatch.h2d", rows=n_rows, chunk=chunk):
+            block = _to_device(in_vals, mesh)
+        with span("pim.dispatch.launch", rows=n_rows, chunk=chunk):
+            if r.use_static and not is_pallas:
+                run = comp.get_static_chain(program, plan, in_names, True,
+                                            r.in_widths, r.out_widths)
+                outs = run(block)
+            elif r.use_static and r.in_base == 0:
+                run = comp.get_static_pallas(program, plan, in_names,
+                                             r.in_widths, r.out_widths)
+                outs = run(block)
             else:
-                fn = (pim_exec_level_fused if is_pallas
-                      else pim_exec_ref_level_fused)
-                static = dict(n_cells=r.sched.n_cells, one_cell=r.one_cell,
-                              in_widths=r.in_widths, out_widths=r.out_widths,
-                              planes=planes)
-            if mesh is None:
-                outs = _aot_call(comp, program, plan, fn,
-                                 (jnp.asarray(in_vals), r.in_idx, r.la,
-                                  r.lb, r.lo, r.out_idx), static)
-            else:
-                outs = _sharded_exec(fn, mesh, not is_pallas, 2, **static)(
-                    _place_rows(in_vals, mesh), r.in_idx, r.la, r.lb, r.lo,
-                    r.out_idx)
+                if is_pallas:
+                    _refuse_interpret_only("slot-scan" if r.kind != "dense"
+                                           else "dense gather")
+                if r.kind != "dense":
+                    fn = (pim_exec_slots_fused if is_pallas
+                          else pim_exec_ref_slots_fused)
+                    static = dict(n_cells=r.sched.n_cells,
+                                  one_cell=r.one_cell,
+                                  in_widths=r.in_widths,
+                                  out_widths=r.out_widths,
+                                  in_base=r.in_base, out_base=r.out_base,
+                                  planes=planes)
+                else:
+                    fn = (pim_exec_level_fused if is_pallas
+                          else pim_exec_ref_level_fused)
+                    static = dict(n_cells=r.sched.n_cells,
+                                  one_cell=r.one_cell,
+                                  in_widths=r.in_widths,
+                                  out_widths=r.out_widths, planes=planes)
+                args = (block, r.in_idx, r.la, r.lb, r.lo, r.out_idx)
+                if mesh is None:
+                    outs = _aot_call(comp, program, plan, fn, args, static)
+                else:
+                    outs = _sharded_exec(fn, mesh, not is_pallas, 2,
+                                         **static)(*args)
 
         # verified-under-fault plans emit the XOR check plane *on the
         # device* (pim_exec.check_words), dispatched asynchronously right
@@ -1339,54 +1357,60 @@ def _dispatch_levelized(program, inputs: Dict[str, np.ndarray], n_rows: int,
             else None
 
         def finalize() -> Dict[str, np.ndarray]:
-            o = np.asarray(outs)                     # blocks until ready
+            with span("pim.dispatch.wait", rows=n_rows, chunk=chunk):
+                jax.block_until_ready(outs)
+            with span("pim.dispatch.d2h", rows=n_rows, chunk=chunk):
+                o = np.asarray(outs)
             if fctx is not None:
                 o = fctx.process_values(o, r.out_widths, r.sched.n_levels,
                                         None if chk is None
                                         else np.asarray(chk))
-            return {n: o[p, :n_rows].astype(np.uint64)
-                    for p, n in enumerate(r.names)}
-        return _traced(finalize)
-    if packed_in is not None:
-        k_in = sum(len(r.sched.pack_cells(n)) for n in in_names)
-        if packed_in.shape[-2] != k_in:
-            raise ValueError(
-                f"packed input stacks {packed_in.shape[-2]} cells, "
-                f"in-ports {in_names} need {k_in}")
-        in_rows = _fit_packed(packed_in, n_words)
-    elif in_names:
-        in_rows = np.concatenate(
-            [_pack_port_words(inputs[n], len(r.sched.pack_cells(n)),
-                              n_words, layout) for n in in_names], axis=-2)
-    else:
-        in_rows = np.zeros(layout.state_shape(0, n_words), np.uint32)
-    if r.use_static and not is_pallas:
-        run = comp.get_static_chain(program, plan, in_names, False,
-                                    r.in_widths, r.out_widths)
-        sub = run(jnp.asarray(in_rows))
-    else:
-        if is_pallas:       # no wide-port or packed-domain static kernel
-            _refuse_interpret_only("slot-scan" if r.kind != "dense"
-                                   else "dense gather")
-        if r.kind != "dense":
-            exec_fn = (pim_exec_slots_io if is_pallas
-                       else pim_exec_ref_slots_io)
-            static = dict(n_cells=r.sched.n_cells, one_cell=r.one_cell,
-                          k_out=r.k_out, in_base=r.in_base,
-                          out_base=r.out_base)
+            with span("pim.dispatch.unpack", rows=n_rows, chunk=chunk):
+                return {n: o[p, :n_rows].astype(np.uint64)
+                        for p, n in enumerate(r.names)}
+        return finalize
+    with span("pim.dispatch.pack", rows=n_rows, chunk=chunk):
+        if packed_in is not None:
+            k_in = sum(len(r.sched.pack_cells(n)) for n in in_names)
+            if packed_in.shape[-2] != k_in:
+                raise ValueError(
+                    f"packed input stacks {packed_in.shape[-2]} cells, "
+                    f"in-ports {in_names} need {k_in}")
+            in_rows = _fit_packed(packed_in, n_words)
+        elif in_names:
+            in_rows = np.concatenate(
+                [_pack_port_words(inputs[n], len(r.sched.pack_cells(n)),
+                                  n_words, layout) for n in in_names],
+                axis=-2)
         else:
-            exec_fn = (pim_exec_level_padded_io if is_pallas
-                       else pim_exec_ref_level_io)
-            static = dict(n_cells=r.sched.n_cells, one_cell=r.one_cell)
-        if mesh is None:
-            sub = _aot_call(comp, program, plan, exec_fn,
-                            (jnp.asarray(in_rows), r.in_idx, r.la, r.lb,
-                             r.lo, r.out_idx), static)
+            in_rows = np.zeros(layout.state_shape(0, n_words), np.uint32)
+    with span("pim.dispatch.h2d", rows=n_rows, chunk=chunk):
+        block = _to_device(in_rows, mesh)
+    with span("pim.dispatch.launch", rows=n_rows, chunk=chunk):
+        if r.use_static and not is_pallas:
+            run = comp.get_static_chain(program, plan, in_names, False,
+                                        r.in_widths, r.out_widths)
+            sub = run(block)
         else:
-            sub = _sharded_exec(exec_fn, mesh, not is_pallas,
-                                in_rows.ndim, **static)(
-                _place_rows(in_rows, mesh), r.in_idx, r.la, r.lb, r.lo,
-                r.out_idx)
+            if is_pallas:   # no wide-port or packed-domain static kernel
+                _refuse_interpret_only("slot-scan" if r.kind != "dense"
+                                       else "dense gather")
+            if r.kind != "dense":
+                exec_fn = (pim_exec_slots_io if is_pallas
+                           else pim_exec_ref_slots_io)
+                static = dict(n_cells=r.sched.n_cells, one_cell=r.one_cell,
+                              k_out=r.k_out, in_base=r.in_base,
+                              out_base=r.out_base)
+            else:
+                exec_fn = (pim_exec_level_padded_io if is_pallas
+                           else pim_exec_ref_level_io)
+                static = dict(n_cells=r.sched.n_cells, one_cell=r.one_cell)
+            args = (block, r.in_idx, r.la, r.lb, r.lo, r.out_idx)
+            if mesh is None:
+                sub = _aot_call(comp, program, plan, exec_fn, args, static)
+            else:
+                sub = _sharded_exec(exec_fn, mesh, not is_pallas,
+                                    in_rows.ndim, **static)(*args)
 
     # on-device check plane for the packed/padded-io path too: the fold
     # runs over the cell axis (-2) of the packed output block
@@ -1394,16 +1418,20 @@ def _dispatch_levelized(program, inputs: Dict[str, np.ndarray], n_rows: int,
         and fctx.faults is not None and fctx.verify is not None else None
 
     def finalize():
-        s = np.asarray(sub)
+        with span("pim.dispatch.wait", rows=n_rows, chunk=chunk):
+            jax.block_until_ready(sub)
+        with span("pim.dispatch.d2h", rows=n_rows, chunk=chunk):
+            s = np.asarray(sub)
         if fctx is not None:
             s = fctx.process_packed(s, r.sched.n_levels,
                                     None if chk is None else np.asarray(chk))
         if packed_out:
             return s
-        return _unpack_sub(s,
-                           [(n, len(r.sched.ports[n])) for n in r.names],
-                           n_rows)
-    return _traced(finalize)
+        with span("pim.dispatch.unpack", rows=n_rows, chunk=chunk):
+            return _unpack_sub(s,
+                               [(n, len(r.sched.ports[n])) for n in r.names],
+                               n_rows)
+    return finalize
 
 
 def run_program(program, inputs: Dict[str, np.ndarray], n_rows: int,
@@ -1520,7 +1548,7 @@ def run_program_streaming(program, inputs: Dict[str, np.ndarray],
         chunk_in = {n: v[start:start + rows_k] for n, v in inputs.items()}
         if vrun is None:
             fin = _dispatch_levelized(program, chunk_in, rows_k, plan,
-                                      pad_rows=chunk)
+                                      pad_rows=chunk, chunk=start // chunk)
         else:
             fin = _verified_dispatch(program, chunk_in, rows_k, plan,
                                      chunk, vrun, start)
@@ -1528,8 +1556,10 @@ def run_program_streaming(program, inputs: Dict[str, np.ndarray],
             parts.append(pending())     # blocks on k-1 while k executes
         pending = fin
     parts.append(pending())
-    return {name: np.concatenate([p[name] for p in parts])
-            for name in parts[0]}
+    with telemetry.TRACER.span("pim.dispatch.concat", rows=n_rows,
+                               chunks=len(parts)):
+        return {name: np.concatenate([p[name] for p in parts])
+                for name in parts[0]}
 
 
 def dispatch_program(program, inputs: Dict[str, np.ndarray], n_rows: int,

@@ -38,6 +38,7 @@ from .core.floatfmt import FORMATS
 from .core.pim_numerics import program_for
 from .kernels import ops as kops
 from .kernels import plan as kplan
+from .runtime import telemetry
 
 __all__ = ["add", "sub", "mul", "div",
            "fp_add", "fp_sub", "fp_mul", "fp_div",
@@ -290,13 +291,15 @@ class Prepared:
     def finish(self, outs: Dict[str, np.ndarray]):
         """Decode raw output-port rows (this request's rows only) into the
         user-facing result."""
-        return self._finish(outs)
+        with telemetry.TRACER.span("pim.finish", op=self.op,
+                                   rows=self.n_rows):
+            return self._finish(outs)
 
     def run(self):
         """Execute standalone through the streaming executor (identical to
         the plain ufunc call)."""
-        return self._finish(_run(self.program, self.inputs, self.n_rows,
-                                 self.plan))
+        return self.finish(_run(self.program, self.inputs, self.n_rows,
+                                self.plan))
 
     def warm(self, rows: int = 1) -> None:
         """Compile without serving: run ``rows`` leading rows (discarded)
@@ -343,7 +346,7 @@ _DTYPE_WIDTHS = {np.dtype(np.uint8): 8, np.dtype(np.uint16): 16,
 
 
 def _int_operands(op, x, y, width):
-    """Broadcast, infer/validate the bit width, and flatten to rows."""
+    """Broadcast, infer the bit width, and flatten to rows."""
     x, y = np.broadcast_arrays(np.asarray(x), np.asarray(y))
     if width is None:
         wx = _DTYPE_WIDTHS.get(x.dtype)
@@ -362,16 +365,21 @@ def _int_operands(op, x, y, width):
         width = int(width)
         if width < 1:
             raise ValueError(f"pim.{op}: width must be >= 1, got {width}")
-        for name, v in (("x", x), ("y", y)):
-            if v.dtype.kind not in "uiO":
-                raise TypeError(
-                    f"pim.{op}: operand {name} must be an integer array, "
-                    f"got dtype {v.dtype}")
-            if v.size and (_vmin(v) < 0 or _vmax(v) >> width):
-                raise ValueError(
-                    f"pim.{op}: operand {name} has values outside "
-                    f"[0, 2**{width})")
     return x.ravel(), y.ravel(), x.shape, width
+
+
+def _check_int_range(op, x, y, width):
+    """Reject operands that are not integers in ``[0, 2**width)`` (an
+    explicit ``width=`` does not come from the dtype, so it is checked)."""
+    for name, v in (("x", x), ("y", y)):
+        if v.dtype.kind not in "uiO":
+            raise TypeError(
+                f"pim.{op}: operand {name} must be an integer array, "
+                f"got dtype {v.dtype}")
+        if v.size and (_vmin(v) < 0 or _vmax(v) >> width):
+            raise ValueError(
+                f"pim.{op}: operand {name} has values outside "
+                f"[0, 2**{width})")
 
 
 def _vmin(v):
@@ -383,21 +391,31 @@ def _vmax(v):
 
 
 def _prepare_int(op, x, y, width, kw) -> Prepared:
-    xr, yr, shape, w = _int_operands(op, x, y, width)
-    plan, parallel = _resolve(kw, family=f"{op}:{w}")
-    prog = program_for("int-parallel" if parallel else "int-serial", op, w)
-    if op == "div":
-        if xr.size and _vmin(yr) == 0:
-            raise ValueError("pim.div: zero divisor")
-        # the divider takes a double-width dividend port z and divisor d
-        inputs = {"z": xr.astype(np.uint64) if xr.dtype != object else xr,
-                  "d": yr}
-        finish = lambda outs: (outs["q"].reshape(shape),
-                               outs["r"].reshape(shape))
-    else:
-        inputs = {"x": xr, "y": yr}
-        finish = lambda outs: outs["z"].reshape(shape)
-    return Prepared(op, prog, inputs, xr.size, plan, finish)
+    span = telemetry.TRACER.span
+    with span("pim.prepare", op=op):
+        with span("pim.prepare.cast", op=op):
+            xr, yr, shape, w = _int_operands(op, x, y, width)
+        if width is not None:
+            with span("pim.prepare.check", op=op, rows=xr.size):
+                _check_int_range(op, xr, yr, w)
+        with span("pim.prepare.bind", op=op, rows=xr.size):
+            plan, parallel = _resolve(kw, family=f"{op}:{w}")
+            prog = program_for("int-parallel" if parallel else "int-serial",
+                               op, w)
+        if op == "div":
+            if xr.size and _vmin(yr) == 0:
+                raise ValueError("pim.div: zero divisor")
+            # the divider takes a double-width dividend port z and
+            # divisor d
+            with span("pim.prepare.cast", op=op, rows=xr.size):
+                z = xr.astype(np.uint64) if xr.dtype != object else xr
+            inputs = {"z": z, "d": yr}
+            finish = lambda outs: (outs["q"].reshape(shape),
+                                   outs["r"].reshape(shape))
+        else:
+            inputs = {"x": xr, "y": yr}
+            finish = lambda outs: outs["z"].reshape(shape)
+        return Prepared(op, prog, inputs, xr.size, plan, finish)
 
 
 def add(x, y, *, width=None, **kw):
@@ -468,50 +486,62 @@ def _check_fp_bits(op, name, bits, fmt, reject_zero=False):
 def _prepare_fp(op, x, y, kw) -> Prepared:
     fmt = kw.pop("fmt", None)
     check = kw.pop("check", True)
-    x, y = np.broadcast_arrays(np.asarray(x), np.asarray(y))
-    if fmt is None:
-        if x.dtype != y.dtype or x.dtype not in _NP_FMT:
-            raise TypeError(
-                f"pim.fp_{op}: operands must share a float16/float32 dtype "
-                f"(got {x.dtype}, {y.dtype}); other formats take fmt= with "
-                "bit-pattern arrays")
-        fmt_name = _NP_FMT[x.dtype]
-        view = _FMT_VIEW[fmt_name]
-        xb = x.ravel().view(view).astype(np.uint64)
-        yb = y.ravel().view(view).astype(np.uint64)
-        decode = lambda bits: bits.astype(view).view(x.dtype).reshape(x.shape)
-    else:
-        if fmt not in FORMATS:
-            raise ValueError(f"pim.fp_{op}: unknown format {fmt!r} "
-                             f"(known: {sorted(FORMATS)})")
-        fmt_name = fmt
-        nbits = FORMATS[fmt].nbits
-        for name, v in (("x", x), ("y", y)):
-            if v.dtype.kind not in "uiO":
-                raise TypeError(
-                    f"pim.fp_{op}: fmt={fmt!r} takes bit-pattern integer "
-                    f"arrays, got dtype {v.dtype}")
-            if v.size and (_vmin(v) < 0 or _vmax(v) >> nbits):
-                raise ValueError(
-                    f"pim.fp_{op}: operand {name} has bit patterns outside "
-                    f"[0, 2**{nbits})")
-        xb = x.ravel().astype(np.uint64)
-        yb = y.ravel().astype(np.uint64)
-        decode = lambda bits: bits.reshape(x.shape)
-    plan, parallel = _resolve(kw, family=f"fp_{op}:{fmt_name}")
-    f = FORMATS[fmt_name]
-    if check and xb.size:
-        _check_fp_bits(f"fp_{op}", "x", xb, f)
-        _check_fp_bits(f"fp_{op}", "y", yb, f, reject_zero=(op == "div"))
-    if parallel and op == "sub":
-        # the bit-parallel suite has no subtractor: flip y's sign, add
-        yb = yb ^ np.uint64(1 << (f.nbits - 1))
-        op = "add"
-    prog = program_for("fp-parallel" if parallel else "fp-serial",
-                       op, fmt_name)
-    finish = lambda outs: decode(np.asarray(outs["z"], np.uint64))
-    return Prepared(f"fp_{op}", prog, {"x": xb, "y": yb}, xb.size, plan,
-                    finish)
+    name = f"fp_{op}"
+    span = telemetry.TRACER.span
+    with span("pim.prepare", op=name):
+        with span("pim.prepare.cast", op=name):
+            x, y = np.broadcast_arrays(np.asarray(x), np.asarray(y))
+            if fmt is None:
+                if x.dtype != y.dtype or x.dtype not in _NP_FMT:
+                    raise TypeError(
+                        f"pim.{name}: operands must share a float16/float32"
+                        f" dtype (got {x.dtype}, {y.dtype}); other formats "
+                        "take fmt= with bit-pattern arrays")
+                fmt_name = _NP_FMT[x.dtype]
+                view = _FMT_VIEW[fmt_name]
+                xb = x.ravel().view(view).astype(np.uint64)
+                yb = y.ravel().view(view).astype(np.uint64)
+                decode = lambda bits: \
+                    bits.astype(view).view(x.dtype).reshape(x.shape)
+        if fmt is not None:
+            if fmt not in FORMATS:
+                raise ValueError(f"pim.{name}: unknown format {fmt!r} "
+                                 f"(known: {sorted(FORMATS)})")
+            fmt_name = fmt
+            nbits = FORMATS[fmt].nbits
+            with span("pim.prepare.check", op=name, rows=x.size):
+                for vname, v in (("x", x), ("y", y)):
+                    if v.dtype.kind not in "uiO":
+                        raise TypeError(
+                            f"pim.{name}: fmt={fmt!r} takes bit-pattern "
+                            f"integer arrays, got dtype {v.dtype}")
+                    if v.size and (_vmin(v) < 0 or _vmax(v) >> nbits):
+                        raise ValueError(
+                            f"pim.{name}: operand {vname} has bit patterns "
+                            f"outside [0, 2**{nbits})")
+            with span("pim.prepare.cast", op=name, rows=x.size):
+                xb = x.ravel().astype(np.uint64)
+                yb = y.ravel().astype(np.uint64)
+            decode = lambda bits: bits.reshape(x.shape)
+        with span("pim.prepare.bind", op=name, rows=xb.size):
+            plan, parallel = _resolve(kw, family=f"{name}:{fmt_name}")
+            # the bit-parallel suite has no subtractor: flip y's sign, add
+            flip = parallel and op == "sub"
+            if flip:
+                op = "add"
+            prog = program_for("fp-parallel" if parallel else "fp-serial",
+                               op, fmt_name)
+        f = FORMATS[fmt_name]
+        if check and xb.size:
+            with span("pim.prepare.check", op=name, rows=xb.size):
+                _check_fp_bits(name, "x", xb, f)
+                _check_fp_bits(name, "y", yb, f, reject_zero=(op == "div"))
+        if flip:
+            with span("pim.prepare.cast", op=name, rows=xb.size):
+                yb = yb ^ np.uint64(1 << (f.nbits - 1))
+        finish = lambda outs: decode(np.asarray(outs["z"], np.uint64))
+        return Prepared(f"fp_{op}", prog, {"x": xb, "y": yb}, xb.size, plan,
+                        finish)
 
 
 def fp_add(x, y, *, fmt=None, **kw):

@@ -17,8 +17,10 @@ One module owns every observable signal the pipeline produces:
   timing through the whole pipeline (prepare -> enqueue -> coalesce/pack
   -> dispatch -> exec -> unpack -> finish), exportable as Chrome-trace /
   Perfetto-compatible JSON (``chrome://tracing``, ``ui.perfetto.dev``).
-  Disabled by default: a disabled span is one attribute read, which is
-  what keeps the tracer inside the <2% tracked-kernel overhead budget.
+  An enabled span is also a ``jax.profiler.TraceAnnotation``, so a
+  profiler trace holds it on the device events' clock.  Disabled by
+  default: a disabled span is one attribute read, which is what keeps
+  the tracer inside the <2% tracked-kernel overhead budget.
 * :class:`PimCostModel` -- the analytical cost gauge: per executed
   program, modeled PIM cycles (gate count + output-copy stage + INIT,
   one column op per cycle -- the paper's §7 execution model) and energy
@@ -36,10 +38,13 @@ Metric naming scheme (dots group, Prometheus rendering maps to ``_``):
 ``pim.cache.*``       compiled-program LRU hit/miss/eviction counters
 ``pim.exec.*``        dispatch counters (dispatches, rows, levels)
 ``pim.model.*``       analytical cost gauges (cycles, energy_pj)
+``pim.jit.*``         XLA backend compiles in the process (compiles,
+                      compile_s; fed by ``kernels.ops``)
 ====================  ====================================================
 
 This module sits at the bottom of the package's import graph: it imports
-only the stdlib and ``core.device_model`` (which imports nothing), so
+only the stdlib and ``core.device_model`` (which imports nothing), and
+JAX only inside an enabled span, so
 ``runtime.faults`` -- itself imported by ``kernels.plan`` -- can depend
 on it without a cycle.
 """
@@ -358,10 +363,13 @@ class CounterGroup:
 
 class _Span:
     """One open span: a context manager that emits a complete ("X") event
-    on exit.  Cheap on purpose -- two perf_counter reads and one deque
-    append."""
+    into the ring on exit and, for its duration, holds a
+    ``jax.profiler.TraceAnnotation`` of the same name and args, so the
+    span also lands in a running profiler's host plane, on the clock of
+    the device's events.  Cheap on purpose -- two perf_counter reads, one
+    annotation and one deque append."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self._tracer = tracer
@@ -370,12 +378,19 @@ class _Span:
         self.args = args
 
     def __enter__(self) -> "_Span":
+        # imported here, only while tracing: telemetry stays at the bottom
+        # of the import graph
+        from jax import profiler
+        self._annotation = profiler.TraceAnnotation(self.name, **self.args)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        self._tracer.event(self.name, self._t0, time.perf_counter(),
-                           cat=self.cat, **self.args)
+        t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        self._tracer.event(self.name, self._t0, t1, cat=self.cat,
+                           **self.args)
 
 
 _NULL_SPAN = contextlib.nullcontext()
@@ -392,7 +407,11 @@ class Tracer:
     dropped), so a long-running server can leave tracing on without
     unbounded growth.  ``enabled`` defaults to False and a disabled
     :meth:`span` returns a shared null context -- one attribute read on
-    the hot path, nothing allocated."""
+    the hot path, nothing allocated.
+
+    An enabled :meth:`span` is also a profiler annotation (see
+    :class:`_Span`); :meth:`event` and :meth:`instant` are back-filled from
+    timestamps after the fact and go to the ring only."""
 
     def __init__(self, capacity: int = 1 << 16):
         self.enabled = False
@@ -402,8 +421,8 @@ class Tracer:
         self._epoch = time.perf_counter()
 
     def span(self, name: str, cat: str = "pim", **args):
-        """Context manager timing one pipeline stage; no-op when the
-        tracer is disabled."""
+        """Context manager timing one pipeline stage, in the ring and in
+        the profiler's trace; no-op when the tracer is disabled."""
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, cat, args)

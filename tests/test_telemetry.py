@@ -243,14 +243,48 @@ def test_record_dispatch_fills_model_counters():
     assert len(kops._compiled) == n_entries
 
 
-def test_dispatch_overhead_is_structural():
+class _Annotation:
+    """Stand-in for ``jax.profiler.TraceAnnotation`` that records each
+    annotation made."""
+    made: list = []
+
+    def __init__(self, name, **args):
+        self.made.append((name, args))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return None
+
+
+def test_dispatch_overhead_is_structural(monkeypatch):
     """The <2% overhead budget on kernel/fp16_add_8k_rows, pinned
     structurally: one dispatch performs exactly one registry lock
     acquisition (one add_many) and zero tracer work when disabled --
-    independent of row count and schedule size."""
+    independent of row count and schedule size, and no profiler
+    annotation is made.  Every shape is compiled before the count: a
+    compile is counted on its own (``pim.jit.*``).  Enabled, each span
+    reaches both the ring and the profiler."""
+    import jax
     from repro.core.pim_numerics import program_for
     prog = program_for("int-serial", "add", 8)
     rng = np.random.default_rng(1)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotation)
+    _Annotation.made = []
+    shapes = [(n, None) for n in (8, 64)] + [(96, 32)]    # 3 chunks
+
+    def run(n, chunk_rows, ins):
+        if chunk_rows is None:
+            return kops.run_program(prog, ins, n, backend="ref")
+        return kops.run_program_streaming(prog, ins, n, backend="ref",
+                                          chunk_rows=chunk_rows)
+
+    cases = [(n, c, {"x": rng.integers(0, 256, n).astype(np.uint64),
+                     "y": rng.integers(0, 256, n).astype(np.uint64)})
+             for n, c in shapes]
+    for case in cases:
+        run(*case)                          # compiles, outside the count
     calls = {"add_many": 0, "observe": 0}
     orig_add_many = telemetry.REGISTRY.add_many
     orig_observe = telemetry.REGISTRY.observe
@@ -266,17 +300,53 @@ def test_dispatch_overhead_is_structural():
     telemetry.REGISTRY.add_many = counting_add_many
     telemetry.REGISTRY.observe = counting_observe
     try:
-        for n in (8, 64):
-            ins = {"x": rng.integers(0, 256, n).astype(np.uint64),
-                   "y": rng.integers(0, 256, n).astype(np.uint64)}
+        for n, chunk_rows, ins in cases:
             before = dict(calls)
-            kops.run_program(prog, ins, n, backend="ref")
-            assert calls["add_many"] - before["add_many"] == 1
+            run(n, chunk_rows, ins)
+            dispatches = 1 if chunk_rows is None else -(-n // chunk_rows)
+            assert calls["add_many"] - before["add_many"] == dispatches
             assert calls["observe"] == before["observe"]
     finally:
         telemetry.REGISTRY.add_many = orig_add_many
         telemetry.REGISTRY.observe = orig_observe
     assert not telemetry.TRACER.enabled    # default: spans are one attr read
+    assert _Annotation.made == []
+    assert telemetry.TRACER.drain() == []
+
+    n, chunk_rows, ins = cases[-1]
+    telemetry.TRACER.enabled = True
+    try:
+        run(n, chunk_rows, ins)
+    finally:
+        telemetry.TRACER.enabled = False
+    ring = [(e["name"], e.get("args", {})) for e in telemetry.TRACER.drain()]
+    assert sorted(ring, key=repr) == sorted(_Annotation.made, key=repr)
+    names = [name for name, _ in ring]
+    for step in ("pack", "h2d", "launch", "wait", "d2h", "unpack"):
+        assert names.count(f"pim.dispatch.{step}") == 3, step
+    assert names.count("pim.dispatch.concat") == 1
+    assert {a["chunk"] for name, a in ring
+            if name == "pim.dispatch.pack"} == {0, 1, 2}
+
+
+def test_compile_counter_counts_new_shapes_only():
+    """``pim.jit.compiles`` rises when a shape compiles and not when the
+    same shape runs again."""
+    import time
+    import jax
+    k = time.time_ns() % 1_000_003         # a program no cache holds yet
+    f = jax.jit(lambda v: v * k + 3)
+
+    def compiles():
+        return telemetry.REGISTRY.counter("pim.jit.compiles")
+
+    n0, s0 = compiles(), telemetry.REGISTRY.counter("pim.jit.compile_s")
+    f(np.arange(37, dtype=np.int32)).block_until_ready()
+    n1 = compiles()
+    assert n1 > n0
+    assert telemetry.REGISTRY.counter("pim.jit.compile_s") > s0
+    f(np.arange(37, dtype=np.int32)).block_until_ready()
+    assert compiles() == n1
 
 
 def test_compiled_cache_hit_miss_counters():
