@@ -460,10 +460,58 @@ _NP_FMT = {np.dtype(np.float16): "fp16", np.dtype(np.float32): "fp32"}
 _FMT_VIEW = {"fp16": np.uint16, "fp32": np.uint32}
 
 
+#: values per block of the operand check's single pass: the masked block
+#: and its reductions stay in cache, and one scratch buffer serves them all.
+#: One 64 Mi-value fp32 operand on a TPU v5e host's CPU: 72 ms in 16 Ki
+#: blocks, 44 in 64 Ki, 39 in 256 Ki, 40 in 1 Mi
+_CHECK_CHUNK = 1 << 18
+
+#: ``check_rows``: operand rows the chunked pass cleared; ``check_slow``:
+#: operands the exact classification read (object arrays, and any operand
+#: the chunked pass did not clear)
+_CHECKS = telemetry.REGISTRY.group("pim.prepare")
+
+
+def _fp_bits_clean(bits, fmt, reject_zero):
+    """True when no element of the 1-D unsigned ``bits`` is NaN/Inf, a
+    subnormal or (``reject_zero``) a zero: one pass in ``bits``' own dtype
+    over the magnitude ``a`` (sign and any bits above the format dropped).
+    NaN/Inf is ``a >= emax << nm``, a subnormal ``0 < a < 1 << nm``, a zero
+    ``a == 0``; ``a - 1`` wraps a zero to the dtype's top, so after the
+    subtraction its min finds subnormals and its max finds zeros."""
+    top = int(np.iinfo(bits.dtype).max)
+    mag = ((1 << (fmt.nbits - 1)) - 1) & top
+    inf = ((1 << fmt.ne) - 1) << fmt.nm
+    sub = min((1 << fmt.nm) - 1, top)
+    buf = np.empty(min(bits.size, _CHECK_CHUNK), bits.dtype)
+    for i in range(0, bits.size, _CHECK_CHUNK):
+        a = buf[:min(_CHECK_CHUNK, bits.size - i)]
+        np.bitwise_and(bits[i:i + a.size], mag, out=a)
+        if int(a.max()) >= inf:
+            return False
+        np.subtract(a, 1, out=a)
+        if int(a.min()) < sub or (reject_zero and int(a.max()) == top):
+            return False
+    return True
+
+
 def _check_fp_bits(op, name, bits, fmt, reject_zero=False):
     """Reject the paper's excluded encodings: NaN/Inf (exponent all-ones)
     and subnormals (exponent 0, mantissa != 0).  Zero is a valid encoding
-    except as a divisor."""
+    except as a divisor.
+
+    Integer arrays are cleared by one chunked pass in their own width
+    (signed ones hold validated non-negative patterns and are read through
+    their unsigned view); object arrays, and an operand that pass does not
+    clear, take the exact classification, which raises the first excluded
+    encoding: NaN/Inf before subnormals before a zero divisor."""
+    if bits.dtype.kind in "ui":
+        flat = bits.reshape(-1)
+        flat = flat.view(flat.dtype.str.replace("i", "u"))
+        if _fp_bits_clean(flat, fmt, reject_zero):
+            _CHECKS.add("check_rows", flat.size)
+            return
+    _CHECKS.add("check_slow")
     b = bits if bits.dtype == object else bits.astype(np.uint64)
     e = np.array([(int(v) >> fmt.nm) & ((1 << fmt.ne) - 1) for v in b.flat],
                  np.int64) if b.dtype == object else \
@@ -499,8 +547,10 @@ def _prepare_fp(op, x, y, kw) -> Prepared:
                         "take fmt= with bit-pattern arrays")
                 fmt_name = _NP_FMT[x.dtype]
                 view = _FMT_VIEW[fmt_name]
-                xb = x.ravel().view(view).astype(np.uint64)
-                yb = y.ravel().view(view).astype(np.uint64)
+                # the operands' bits in their own width: views, no copy;
+                # xc/yc are what the check reads
+                xb = xc = x.ravel().view(view)
+                yb = yc = y.ravel().view(view)
                 decode = lambda bits: \
                     bits.astype(view).view(x.dtype).reshape(x.shape)
         if fmt is not None:
@@ -523,6 +573,7 @@ def _prepare_fp(op, x, y, kw) -> Prepared:
                 xb = x.ravel().astype(np.uint64)
                 yb = y.ravel().astype(np.uint64)
             decode = lambda bits: bits.reshape(x.shape)
+            xc, yc = x, y      # in the caller's width, not the uint64 copies
         with span("pim.prepare.bind", op=name, rows=xb.size):
             plan, parallel = _resolve(kw, family=f"{name}:{fmt_name}")
             # the bit-parallel suite has no subtractor: flip y's sign, add
@@ -534,11 +585,11 @@ def _prepare_fp(op, x, y, kw) -> Prepared:
         f = FORMATS[fmt_name]
         if check and xb.size:
             with span("pim.prepare.check", op=name, rows=xb.size):
-                _check_fp_bits(name, "x", xb, f)
-                _check_fp_bits(name, "y", yb, f, reject_zero=(op == "div"))
+                _check_fp_bits(name, "x", xc, f)
+                _check_fp_bits(name, "y", yc, f, reject_zero=(op == "div"))
         if flip:
             with span("pim.prepare.cast", op=name, rows=xb.size):
-                yb = yb ^ np.uint64(1 << (f.nbits - 1))
+                yb = yb ^ yb.dtype.type(1 << (f.nbits - 1))
         finish = lambda outs: decode(np.asarray(outs["z"], np.uint64))
         return Prepared(f"fp_{op}", prog, {"x": xb, "y": yb}, xb.size, plan,
                         finish)
@@ -634,10 +685,11 @@ def lazy(x, *, width=None, fmt=None, check=True) -> LazyExpr:
     x = np.asarray(x)
     if fmt is None and x.dtype in _NP_FMT:
         fmt = _NP_FMT[x.dtype]
-        bits = x.view(_FMT_VIEW[fmt]).astype(np.uint64)
+        bits = x.view(_FMT_VIEW[fmt])
         if check and bits.size:
             _check_fp_bits("lazy", "x", bits, FORMATS[fmt])
-        return LazyExpr("fp", value=bits, fmt=fmt, dtype=x.dtype)
+        return LazyExpr("fp", value=bits.astype(np.uint64), fmt=fmt,
+                        dtype=x.dtype)
     if fmt is not None:
         if fmt not in FORMATS:
             raise ValueError(f"pim.lazy: unknown format {fmt!r} "
@@ -649,10 +701,9 @@ def lazy(x, *, width=None, fmt=None, check=True) -> LazyExpr:
         if x.size and (_vmin(x) < 0 or _vmax(x) >> nbits):
             raise ValueError(f"pim.lazy: bit patterns outside "
                              f"[0, 2**{nbits})")
-        bits = x.astype(np.uint64)
-        if check and bits.size:
-            _check_fp_bits("lazy", "x", bits, FORMATS[fmt])
-        return LazyExpr("fp", value=bits, fmt=fmt)
+        if check and x.size:
+            _check_fp_bits("lazy", "x", x, FORMATS[fmt])
+        return LazyExpr("fp", value=x.astype(np.uint64), fmt=fmt)
     if width is None:
         width = _DTYPE_WIDTHS.get(x.dtype)
         if width is None:

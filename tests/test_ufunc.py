@@ -118,6 +118,158 @@ def test_fp_ufunc_validation():
     assert out.shape == (4,)
 
 
+def _exact_fp_check(op, name, bits, fmt, reject_zero=False):
+    """The operand check as it was before its chunked pass: uint64 (or
+    object) exponent and mantissa arrays, each encoding class in turn.
+    Kept as the oracle of the check's messages and their precedence."""
+    b = bits if bits.dtype == object else bits.astype(np.uint64)
+    e = np.array([(int(v) >> fmt.nm) & ((1 << fmt.ne) - 1) for v in b.flat],
+                 np.int64) if b.dtype == object else \
+        ((b >> np.uint64(fmt.nm)) & np.uint64((1 << fmt.ne) - 1)
+         ).astype(np.int64)
+    m = np.array([int(v) & ((1 << fmt.nm) - 1) for v in b.flat], np.int64) \
+        if b.dtype == object else \
+        (b & np.uint64((1 << fmt.nm) - 1)).astype(np.int64)
+    emax = (1 << fmt.ne) - 1
+    if (e == emax).any():
+        raise ValueError(f"pim.{op}: operand {name} contains NaN/Inf "
+                         "(excluded by the PIM suite)")
+    if ((e == 0) & (m != 0)).any():
+        raise ValueError(f"pim.{op}: operand {name} contains subnormals "
+                         "(excluded by the PIM suite)")
+    if reject_zero and ((e == 0) & (m == 0)).any():
+        raise ValueError(f"pim.{op}: zero divisor")
+
+
+def _patterns(f):
+    """Named bit patterns of format ``f`` at the edges of each class."""
+    emax, sign = (1 << f.ne) - 1, 1 << (f.nbits - 1)
+    inf = emax << f.nm
+    return {"+inf": inf, "-inf": sign | inf,
+            "qnan": inf | (1 << (f.nm - 1)), "snan": inf | 1,
+            "min_sub": 1, "max_sub": (1 << f.nm) - 1,
+            "+0": 0, "-0": sign,
+            "min_normal": 1 << f.nm,
+            "max_normal": ((emax - 1) << f.nm) | ((1 << f.nm) - 1)}
+
+
+# (label, format, container dtype, native float dtype or None for fmt=)
+_CHECK_FORMATS = [("fp16", "fp16", np.uint16, np.float16),
+                  ("fp32", "fp32", np.uint32, np.float32),
+                  ("bf16-u16", "bf16", np.uint16, None),
+                  ("bf16-u64", "bf16", np.uint64, None),
+                  ("bf16-i64", "bf16", np.int64, None),
+                  ("bf16-u16be", "bf16", np.dtype(">u2"), None)]
+_ROWS = pim._CHECK_CHUNK + 17        # a second, ragged chunk
+
+
+def _check_cases():
+    for label, fmt, cont, native in _CHECK_FORMATS:
+        for pat in _patterns(FORMATS[fmt]):
+            for role in ("x", "y"):
+                for row in (0, pim._CHECK_CHUNK + 3):
+                    yield pytest.param(fmt, cont, native,
+                                       {role: [(row, pat)]},
+                                       id=f"{label}-{pat}-{role}@{row}")
+        # NaN late, subnormal early in x: the NaN/Inf message still wins
+        yield pytest.param(fmt, cont, native,
+                           {"x": [(2, "min_sub"), (_ROWS - 1, "qnan")]},
+                           id=f"{label}-precedence")
+        # a zero dividend is a valid operand of fp_div
+        yield pytest.param(fmt, cont, native, {"x": [(5, "+0")]},
+                           id=f"{label}-zero-dividend")
+
+
+@pytest.mark.parametrize("fmt,cont,native,planted", list(_check_cases()))
+def test_fp_check_matches_exact_classification(fmt, cont, native, planted):
+    """The chunked check raises exactly what the exact classification
+    raises (type, message, precedence: x before y, NaN/Inf before
+    subnormal before zero divisor), and nothing on a clean operand."""
+    f = FORMATS[fmt]
+    pats = _patterns(f)
+    ops = {n: np.full(_ROWS, pats["min_normal"] | (3 << (f.nm - 2)), cont)
+           for n in ("x", "y")}
+    for n, places in planted.items():
+        for row, pat in places:
+            ops[n][row] = pats[pat]
+
+    def outcome(fn):
+        try:
+            fn()
+        except (ValueError, TypeError) as e:
+            return type(e), str(e)
+        return None
+
+    def exact():
+        _exact_fp_check("fp_div", "x", ops["x"], f)
+        _exact_fp_check("fp_div", "y", ops["y"], f, reject_zero=True)
+
+    if native is None:
+        args, kw = (ops["x"], ops["y"]), {"fmt": fmt}
+    else:
+        args, kw = (ops["x"].view(native), ops["y"].view(native)), {}
+    want = outcome(exact)
+    assert outcome(lambda: pim.prepare("fp_div", *args, **kw)) == want
+    clean = all(pat in ("+0", "-0", "min_normal", "max_normal")
+                for row, pat in planted.get("x", ())) and \
+        all(pat in ("min_normal", "max_normal")
+            for row, pat in planted.get("y", ()))
+    assert (want is None) == clean
+
+
+@pytest.mark.parametrize("dtype", [np.float16, np.float32])
+def test_fp_prepare_hands_on_views(dtype):
+    """A contiguous native operand reaches the dispatcher as a view of its
+    bits in its own width: no cast, no copy."""
+    x = np.linspace(1, 2, 257, dtype=dtype)
+    y = np.linspace(3, 4, 257, dtype=dtype)
+    prep = pim.prepare("fp_mul", x, y)
+    for n, v in (("x", x), ("y", y)):
+        assert prep.inputs[n].dtype == pim._FMT_VIEW[pim._NP_FMT[x.dtype]]
+        assert np.shares_memory(prep.inputs[n], v)
+
+
+@pytest.mark.parametrize("dtype,op,kw", [
+    (np.float16, "fp_add", {}), (np.float32, "fp_mul", {}),
+    (np.float16, "fp_sub", {"parallel": True}),
+    (np.float32, "fp_sub", {"parallel": True})])
+def test_fp_prepare_run_bit_identical(dtype, op, kw):
+    """prepare(...).run() on the narrow views equals numpy bit for bit,
+    bit-parallel sub (y's sign flipped in the view's own dtype) too; the
+    caller's y is left as it was."""
+    rng = np.random.default_rng(7)
+    x = (rng.standard_normal(96) + 3).astype(dtype)
+    y = (rng.standard_normal(96) * 2).astype(dtype)
+    y = np.where(y == 0, np.asarray(1, dtype), y)
+    y0 = y.copy()
+    got = pim.prepare(op, x, y, **kw).run()
+    want = {"fp_add": x + y, "fp_sub": x - y, "fp_mul": x * y}[op]
+    assert got.dtype == dtype
+    assert np.array_equal(got.view(f"u{x.itemsize}"),
+                          want.astype(dtype).view(f"u{x.itemsize}"))
+    assert np.array_equal(y, y0)
+
+
+def test_fp_check_counters():
+    """``pim.prepare.check_rows`` counts the rows the chunked pass cleared
+    (both operands of a clean call); ``check_slow`` counts operands the
+    exact classification read: object arrays, and a call that raises."""
+    from repro.runtime.telemetry import REGISTRY
+    g = REGISTRY.group("pim.prepare")
+    rows0, slow0 = g["check_rows"], g["check_slow"]
+    x = np.linspace(1, 2, 1000, dtype=np.float32)
+    pim.prepare("fp_add", x, x)
+    assert g["check_rows"] - rows0 == 2 * x.size
+    assert g["check_slow"] == slow0
+    bits = np.array([int(v) for v in x.view(np.uint32) >> 16], object)
+    pim.prepare("fp_add", bits, bits, fmt="bf16")
+    assert g["check_slow"] == slow0 + 2
+    with pytest.raises(ValueError, match="zero divisor"):
+        pim.prepare("fp_div", x, np.zeros_like(x))
+    assert g["check_slow"] == slow0 + 3
+    assert g["check_rows"] - rows0 == 3 * x.size
+
+
 # ----------------------------------------------- streaming + sharded 1M row
 
 def test_stream_1m_rows_bit_exact_vs_oracle():
