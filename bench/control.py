@@ -1,7 +1,9 @@
 #!/usr/bin/env python3
 """The control of ``correct``: the plain reference one precision down,
 put in the program's place, run through the harness at a cell's own
-size.  Every seed has to come out not correct.
+size.  Every seed has to come out not correct.  On the ``serve`` path
+the control is a server of the same wire format
+(:func:`serve_control`), driven by the harness's own client.
 
     python3 bench/control.py --workload <cell> --seconds <s> --seeds <n> ...
 
@@ -37,6 +39,34 @@ class Control:
         return {}
 
 
+def serve_control(inp, outp) -> None:
+    """A JSON-lines server that answers each request line, in order, with
+    ``reference.control`` of its operands, in the wire format of
+    ``--pim-serve``."""
+    import numpy as np
+    from bench import reference
+    for line in inp:
+        req = json.loads(line)
+        x = np.asarray(req["x"], req["dtype"])
+        y = np.asarray(req["y"], req["dtype"])
+        out = reference.as_result(req["op"],
+                                  reference.control(req["op"], x, y),
+                                  x.dtype)
+        resp = {"op": req["op"], "rows": len(x), "queue_us": 0.0}
+        if isinstance(out, tuple):
+            resp["q"], resp["r"] = (part.tolist() for part in out)
+        else:
+            resp["result"] = out.tolist()
+        print(json.dumps(resp), file=outp, flush=True)
+
+
+def control_system(harness, cell):
+    """The control in the place of the system the cell's mix names."""
+    if cell.mix.get("path", "ufunc") == "serve":
+        return harness.Served(cell.config, serve=serve_control)
+    return Control(harness.Ufuncs(cell.config))
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
@@ -49,8 +79,8 @@ def main(argv=None) -> int:
     try:
         cell = harness.load_cell(args.workload)
         jax, devices = harness.start_jax(cell.chips)
-        system = Control(harness.Ufuncs(cell.config))
         for seed in args.seeds:
+            system = control_system(harness, cell)
             line = harness.run_cell(
                 cell, seed, args.seconds, False, jax=jax, devices=devices,
                 system=system, t_process=time.perf_counter(),

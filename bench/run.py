@@ -83,7 +83,7 @@ def main(argv=None) -> int:
         jax, devices = harness.start_jax(cell.chips, bool(args.trace))
         line = harness.run_cell(
             cell, args.seed, args.seconds, bool(args.trace), jax=jax,
-            devices=devices, system=harness.Ufuncs(cell.config),
+            devices=devices, system=harness.make_system(cell),
             t_process=T_PROCESS,
             emit=lambda d: print(json.dumps(d), flush=True))
     except harness.BenchError as exc:

@@ -4,20 +4,29 @@ run on the CPU at a small size -- correct against the reference, not
 correct under the control or with the timed path broken underneath."""
 
 import dataclasses
+import functools
 import json
 import os
 import shutil
 import subprocess
 import sys
+import time
 
 import jax
 import numpy as np
 import pytest
 
 from bench import control, harness, reference, traffic, work
+from bench import spans as bspans
+from bench import trace as btrace
 from repro.kernels import ops as kops
+from repro.launch import serve as srv
 
 ROWS = 2048
+UFUNC_CELLS = ["fp32-fig9-64Mi", "int32-fig9-4Mi", "fp32-fig9-64Mi-x4"]
+SERVE = "fp32-serve-open"
+#: The serve mix at a size and rate the CPU holds: a block is 9 requests.
+SMALL_SERVE = {"row_counts": {"64": 2, "256": 1}, "rate_per_s": 400.0}
 
 
 @dataclasses.dataclass
@@ -30,9 +39,29 @@ class FakeDevice:
         return None
 
 
+def serve_cell() -> harness.Cell:
+    """The served cell, which BENCHMARK.json does not name yet (PERF.md
+    §7): the ``fig9-fp32`` configuration under the ``serve-open`` mix,
+    reporting every metric that reads the served path."""
+    spec = json.load(open(os.path.join(harness.ROOT, "BENCHMARK.json")))
+    config = harness._read_json(os.path.join(harness.BENCH, "configs",
+                                             "fig9-fp32.json"))
+    mix = harness._read_json(os.path.join(harness.BENCH, "traffic",
+                                          "serve-open.json"))
+    per_layer = [m["name"] for m in spec["per_layer"]] + ["serve_queue_pct"]
+    units = {m["name"]: m["unit"]
+             for m in spec["end_to_end"] + spec["per_layer"]}
+    return harness.Cell(name=SERVE, chips=1, config=config, mix=mix,
+                        end_to_end=[m["name"] for m in spec["end_to_end"]],
+                        per_layer=per_layer,
+                        units=dict(units, serve_queue_pct="%"))
+
+
 def small_cell(name: str, **mix) -> harness.Cell:
-    cell = harness.load_cell(name)
+    cell = serve_cell() if name == SERVE else harness.load_cell(name)
     cell.config = dict(cell.config, rows=ROWS)
+    if name == SERVE:
+        mix = dict(SMALL_SERVE, **mix)
     cell.mix = dict(cell.mix, **mix)
     return cell
 
@@ -97,7 +126,10 @@ def test_a_traced_run_adds_its_compiler_flags(monkeypatch):
     monkeypatch.setenv("LIBTPU_INIT_ARGS", "--machine_flag=1")
     for name in ("JAX_COMPILATION_CACHE_DIR",
                  "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "TPU_LOG_DIR"):
-        monkeypatch.delenv(name, raising=False)
+        # set first, so that what start_jax sets is undone after the test
+        # and no later test of this process inherits it
+        monkeypatch.setenv(name, "")
+        monkeypatch.delenv(name)
     saved = {k: getattr(jax.config, k) for k in (
         "jax_compilation_cache_dir", "jax_compilation_cache_max_size")}
     try:
@@ -126,17 +158,59 @@ def test_every_cell_names_files_that_exist():
             assert callable(harness.load_metric(m))
 
 
-@pytest.mark.parametrize("name", ["fp32-fig9-64Mi", "int32-fig9-4Mi"])
+def test_a_metric_is_read_only_in_the_cells_it_lists():
+    """Every cell reads every metric; a ``workloads`` list in
+    ``BENCHMARK.json`` only names the cells where the reader finds
+    something, and each reader of the harness's ``bench.*`` spans or of
+    one program span finds nothing, not a false 0, in a run that has none
+    of them."""
+    spec = json.load(open(os.path.join(harness.ROOT, "BENCHMARK.json")))
+    names = [w["name"] for w in spec["workloads"]]
+    for n in names:
+        cell = harness.load_cell(n)
+        assert cell.end_to_end == ["rows_per_s", "call_p95_s", "setup_s"]
+        assert cell.per_layer == [m["name"] for m in spec["per_layer"]]
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        assert set(m.get("workloads", names)) <= set(names), m["name"]
+    served = btrace.Reduced(window_s=1.0, chips=1, busy_s=0.5, span_s={},
+                            dispatch_host_s=0.0, executor_s=0.5,
+                            kernels={}, device_ops={}, idle_by_span={})
+    readings = harness.Readings(
+        setup_s=1.0, calls=[], window_s=1.0, work={}, peaks={},
+        trace=served, spans=bspans.ProgramSpans(
+            window_s=1.0, intervals={"pim.dispatch.pack": [(0, 10)]},
+            idle_by_span={}))
+    for name in ("frontend_pct", "dispatch_host_pct", "prepare_check_pct"):
+        assert harness.load_metric(name)(readings) is None, name
+
+
+@pytest.mark.parametrize("name", ["fp32-fig9-64Mi", "int32-fig9-4Mi",
+                                  SERVE])
 def test_a_run_on_the_cpu_is_correct(name):
     cell = small_cell(name)
-    line, lines = drive(cell, harness.Ufuncs(cell.config))
+    line, lines = drive(cell, harness.make_system(cell))
     assert line["correct"], line["checks"]
     assert line["failed"] == 0 and line["attempted"] % 3 == 0
     assert set(line["metrics"]) == {"rows_per_s", "call_p95_s", "setup_s"}
     assert list(line)[-1] == "checks"
     assert all(c == {"value": 0, "limit": 0}
                for c in line["checks"].values())
-    assert lines[0]["window"]["compiles"] == 0
+    window = lines[0]["window"]
+    if name != SERVE:
+        assert window["compiles"] == 0
+        assert set(lines[0]) == {"window", "counters"}
+        return
+    # the served path's compiles are timing's to decide: reported, as is
+    # how late the writer ran and how many blocks warmed it up
+    assert line["attempted"] % 9 == 0
+    assert "mismatch_rows.fp_div" in line["checks"]
+    assert line["checks"]["extra_responses"] == {"value": 0, "limit": 0}
+    assert window["compiles"] >= 0 and window["compile_s"] >= 0
+    client = lines[0]["client"]
+    assert 1 <= client["warmup_blocks"] <= harness.WARMUP_BLOCKS
+    assert 0 <= client["writer_late_s"]["p95"] <= \
+        client["writer_late_s"]["max"]
+    assert set(window["call_s_by_rows"]) == {64, 256}
 
 
 def test_seed_fixes_operands_and_order():
@@ -152,6 +226,19 @@ def test_seed_fixes_operands_and_order():
     assert len(a.pool) == 2 and not np.array_equal(a.pool[0][0],
                                                    a.pool[1][0])
     assert [a.pair(i) for i in range(4)] == [0, 1, 0, 1]
+    assert a.path == "ufunc"
+    # the serve mix as committed: 63 requests of 64,512 rows a block
+    serve = serve_cell()
+    a = traffic.make(serve.mix, serve.config, 3 * 2**31)
+    b = traffic.make(serve.mix, serve.config, 3 * 2**31)
+    assert a.block == b.block and a.path == "serve" and a.open_loop
+    for (xa, ya), (xb, yb) in zip(a.pool, b.pool):
+        assert np.array_equal(xa, xb) and np.array_equal(ya, yb)
+        assert xa.dtype == np.float32 and len(xa) == 16384
+    assert len(a.block) == 63 and sum(s.rows for s in a.block) == 64512
+    for op in serve.config["ops"]:
+        sizes = sorted(s.rows for s in a.block if s.op == op)
+        assert sizes == [64] * 16 + [1024] * 4 + [16384]
 
 
 def test_every_seed_sends_the_same_work_in_its_own_order():
@@ -168,9 +255,22 @@ def test_every_seed_sends_the_same_work_in_its_own_order():
                                                             rel=0.2)
     for bad in ({"arrivals": "bursty"}, {"order": "sorted"},
                 {"op_counts": {"fp_add": 1}},
-                {"arrivals": "poisson", "rate_per_s": 0}):
+                {"arrivals": "poisson", "rate_per_s": 0},
+                {"path": "rpc"}, {"row_counts": {"64": 2}},
+                {"rows": None, "row_counts": {"64": 0}}):
         with pytest.raises(ValueError):
-            traffic.make(dict(mix, **bad), config, 1)
+            traffic.make({k: v for k, v in dict(mix, **bad).items()
+                          if v is not None}, config, 1)
+    # sizes by count: each op call of op_counts at each size, that often
+    counted = dict(mix, row_counts={"64": 3, "1024": 1})
+    del counted["rows"]
+    blocks = [traffic.make(counted, config, seed).block
+              for seed in (1, 2**40)]
+    assert blocks[0] != blocks[1] and len(blocks[0]) == 24
+    for key in (lambda s: (s.op, s.rows), lambda s: s.gap_s):
+        assert sorted(map(key, blocks[0])) == sorted(map(key, blocks[1]))
+    assert sorted(s.rows for s in blocks[0] if s.op == "mul") == \
+        [64] * 6 + [1024] * 2
 
 
 def test_an_open_loop_counts_the_wait_from_arrival():
@@ -230,10 +330,11 @@ def test_a_bit_parallel_configuration_runs_bit_parallel():
     assert line["correct"], line["checks"]
 
 
-@pytest.mark.parametrize("name", ["fp32-fig9-64Mi", "int32-fig9-4Mi"])
+@pytest.mark.parametrize("name", ["fp32-fig9-64Mi", "int32-fig9-4Mi",
+                                  SERVE])
 def test_the_control_is_not_correct(name):
     cell = small_cell(name)
-    line, _ = drive(cell, control.Control(harness.Ufuncs(cell.config)))
+    line, _ = drive(cell, control.control_system(harness, cell))
     assert not line["correct"]
     assert max(c["value"] for c in line["checks"].values()) > 0
 
@@ -287,6 +388,175 @@ def test_a_broken_timed_path_is_not_correct(monkeypatch, name, fault):
     harness.warm_up(system, traffic.make(cell.mix, cell.config, 1))
     fault(monkeypatch)                          # compiled before breaking
     line, _ = drive(cell, system)
+    assert not line["correct"]
+    assert line["failed"] > 0
+
+
+def _reference_server(stall_s: float = 0.0):
+    """A server of ``--pim-serve``'s wire format that answers each request,
+    in order, with the plain reference, after ``stall_s`` seconds in which
+    it reads nothing."""
+    def serve(inp, outp):
+        time.sleep(stall_s)
+        for line in inp:
+            req = json.loads(line)
+            x = np.asarray(req["x"], req["dtype"])
+            y = np.asarray(req["y"], req["dtype"])
+            out = reference.as_result(
+                req["op"], reference.reference(req["op"], x, y), x.dtype)
+            print(json.dumps({"op": req["op"], "rows": len(x),
+                              "queue_us": 0.0, "result": out.tolist()}),
+                  file=outp, flush=True)
+    return serve
+
+
+def test_the_served_warm_up_sends_blocks_until_one_compiles_nothing(
+        monkeypatch):
+    """The served warm-up sends whole blocks through the server at the
+    mix's rate and stops after the first block that adds no
+    ``pim.jit.compiles``, or after :data:`harness.WARMUP_BLOCKS`."""
+    cell = small_cell(SERVE)
+    tr = traffic.make(cell.mix, cell.config, 2**31 + 11)
+    for compiling, blocks in ((2, 3), (99, harness.WARMUP_BLOCKS)):
+        system = harness.Served(cell.config, serve=_reference_server())
+        sent = []
+
+        def counters(system=system, compiling=compiling):
+            n = system.sent // len(tr.block)
+            sent.append(n)
+            return {"pim.jit.compiles": min(n, compiling)}
+        monkeypatch.setattr(system, "counters", counters)
+        try:
+            system.warm_up(tr)
+        finally:
+            system.close()
+        assert system.warmup_blocks == blocks
+        assert system.sent == blocks * len(tr.block)
+
+
+def test_a_stalled_server_counts_the_wait_from_arrival():
+    """A server that reads nothing for half a second: the requests' pipe
+    fills, the writer runs late, and every latency still runs from the
+    request's arrival on the schedule, not from its late write."""
+    cell = small_cell(SERVE, row_counts={"64": 2, "16384": 1},
+                      rate_per_s=200.0)
+    config = dict(cell.config, rows=16384)
+    tr = traffic.make(cell.mix, config, 2**33 + 3)
+    stall = 0.5
+    system = harness.Served(config, serve=_reference_server(stall))
+    system.start(tr)
+    t0 = time.perf_counter()
+    calls, results, window_s = system.send(tr, 2)
+    assert system.close() == 0
+    arrival = np.cumsum([s.gap_s for s in tr.block * 2])
+    assert arrival[-1] < stall / 2           # all due within the stall
+    late = system.report["writer_late_s"]
+    assert late["max"] > stall / 4           # the full pipe held it back
+    for c, due in zip(calls, arrival):
+        assert c.error is None
+        assert c.seconds >= stall - due - 1e-3
+    assert max(c.seconds for c in calls) >= late["max"]
+    assert window_s >= stall and time.perf_counter() - t0 >= window_s
+    checks = harness.check(calls, results, tr)
+    assert all(c["value"] == 0 for c in checks.values())
+
+
+class _Lines:
+    """The server's response stream, each line handed to ``edit`` with its
+    index in the stream; what ``edit`` returns is written in its place."""
+
+    def __init__(self, outp, edit):
+        self.outp, self.edit, self.k, self.part = outp, edit, 0, ""
+
+    def write(self, text):
+        self.part += text
+        while "\n" in self.part:
+            line, self.part = self.part.split("\n", 1)
+            for out in self.edit(self.k, line):
+                self.outp.write(out + "\n")
+            self.k += 1
+        return len(text)
+
+    def flush(self):
+        self.outp.flush()
+
+
+def _lines_edited(edit):
+    """A fault in the wire: the server's response lines through ``edit``.
+    A block of the small serve mix is 9 requests, so line 4 of every 9 is
+    inside a block whatever phase it falls in."""
+    def patch(monkeypatch):
+        serve = functools.partial(srv.serve_pim_batched,
+                                  **harness.SERVE_OPTIONS)
+        # long enough for the server to end under a loaded test host,
+        # short enough that a dropped response costs seconds, not minutes
+        monkeypatch.setattr(harness, "ANSWER_GRACE_S", 5.0)
+        return lambda inp, outp: serve(inp, _Lines(outp, edit))
+    return patch
+
+
+def _dropped(k, line):
+    return [] if k % 9 == 4 else [line]
+
+
+def _swapped():
+    held = []
+
+    def edit(k, line):
+        if k % 9 == 4:
+            held.append(line)
+            return []
+        return [line, held.pop()] if k % 9 == 5 else [line]
+    return edit
+
+
+def _error_body(k, line):
+    if k % 9 != 4:
+        return [line]
+    return [json.dumps({"error": {"code": "internal", "message": "planted",
+                                  "retriable": True}})]
+
+
+def _group_answer_altered(monkeypatch):
+    orig = kops.run_program_groups
+
+    def altered(groups):
+        outs = orig(groups)
+        for o in outs:
+            name = sorted(o)[0]
+            o[name] = o[name].copy()
+            o[name][len(o[name]) // 3] ^= 1
+        return outs
+    monkeypatch.setattr(kops, "run_program_groups", altered)
+
+
+def _group_half_left_out(monkeypatch):
+    orig = kops.run_program_groups
+
+    def half(groups):
+        groups = list(groups)
+        kept = [dict(g, n_rows=g["n_rows"] - g["n_rows"] // 2,
+                     inputs={n: v[:g["n_rows"] - g["n_rows"] // 2]
+                             for n, v in g["inputs"].items()})
+                for g in groups]
+        return [{n: np.concatenate([v, np.zeros(g["n_rows"] - len(v),
+                                                v.dtype)])
+                 for n, v in o.items()}
+                for g, o in zip(groups, orig(kept))]
+    monkeypatch.setattr(kops, "run_program_groups", half)
+
+
+@pytest.mark.parametrize("fault", [
+    _group_answer_altered, _lines_edited(_dropped),
+    _lines_edited(_swapped()), _lines_edited(_error_body),
+    _group_half_left_out, _state_unchanged],
+    ids=["answer_altered", "response_dropped", "responses_swapped",
+         "error_body", "half_the_rows_left_out", "level_loop_skipped"])
+def test_a_broken_served_path_is_not_correct(monkeypatch, fault):
+    monkeypatch.setattr(harness, "WARMUP_BLOCKS", 2)
+    cell = small_cell(SERVE)
+    serve = fault(monkeypatch)
+    line, _ = drive(cell, harness.Served(cell.config, serve=serve))
     assert not line["correct"]
     assert line["failed"] > 0
 

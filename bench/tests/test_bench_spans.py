@@ -161,15 +161,9 @@ def test_program_spans_from_a_recorded_cpu_trace(tmp_path):
     assert 0 < p.pct("pim.dispatch.pack") < 100
 
 
-@pytest.mark.parametrize("name", ["fp32-fig9-64Mi", "int32-fig9-4Mi"])
-def test_a_traced_run_of_the_harness_leaves_the_tracer_off(name,
-                                                           monkeypatch):
-    """The harness's traced path end to end on the CPU, with one
-    executable event planted on a chip that the CPU trace lacks: the
-    program's tracer stays off, so the block records no ``pim.*`` span
-    and the line is the harness's own."""
-    from repro.runtime import telemetry
-    from bench.tests.test_bench_harness import drive, small_cell
+def _with_a_chip(monkeypatch):
+    """``btrace.load`` with one executable event planted on a chip, which
+    the CPU trace lacks; returns the list of paths it loaded."""
     load, paths = btrace.load, []
 
     def with_a_chip(path):
@@ -181,6 +175,19 @@ def test_a_traced_run_of_the_harness_leaves_the_tracer_off(name,
                             lo + (hi - lo) / 2)]}
         return raw
     monkeypatch.setattr(btrace, "load", with_a_chip)
+    return paths
+
+
+@pytest.mark.parametrize("name", ["fp32-fig9-64Mi", "int32-fig9-4Mi"])
+def test_a_traced_run_of_the_harness_leaves_the_tracer_off(name,
+                                                           monkeypatch):
+    """The harness's traced path end to end on the CPU: the program's
+    tracer is on for the profiled block only, so the block records the
+    ``pim.*`` spans, the line gains their shares and the idle gaps by
+    program span, and the tracer is off again after, its ring empty."""
+    from repro.runtime import telemetry
+    from bench.tests.test_bench_harness import drive, small_cell
+    paths = _with_a_chip(monkeypatch)
     cell = small_cell(name)
     telemetry.TRACER.drain()
     line, _ = drive(cell, harness.Ufuncs(cell.config), traced=True)
@@ -188,6 +195,43 @@ def test_a_traced_run_of_the_harness_leaves_the_tracer_off(name,
     assert not telemetry.TRACER.enabled
     assert telemetry.TRACER.drain() == []
     assert len(paths) == 1
-    assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
+    assert set(line["breakdown"]) == {"device_ops", "idle_gaps",
+                                      "idle_gaps_by_program_span"}
     assert all(n.startswith("bench.") or n == btrace.OUTSIDE
                for n, _ in line["breakdown"]["idle_gaps"])
+    assert any(n.startswith(bspans.PREFIX) for n, _ in
+               line["breakdown"]["idle_gaps_by_program_span"])
+    shares = set(bspans.SHARES) - (
+        set() if name.startswith("fp32") else {"prepare_check_pct"})
+    own = {"frontend_pct", "dispatch_host_pct", "pim_exec_roofline",
+           "gate_rows_per_dev_s", "device_idle_pct"}
+    assert own | set(bspans.SHARES) <= set(cell.per_layer)
+    assert set(line["metrics"]) == own | shares
+    assert all(0 < line["metrics"][n]["value"] < 100 for n in shares)
+
+
+def test_a_traced_served_run_reads_the_server(monkeypatch):
+    """The served path under the profiler on the CPU: the server's threads
+    record their ``pim.*`` spans in the block, the line reads the queue's
+    share of the latency and the program's shares, and the harness's own
+    front-end and dispatcher metrics, which read ``bench.*`` spans that
+    the served path has not, read nothing there."""
+    from repro.runtime import telemetry
+    from bench.tests.test_bench_harness import SERVE, drive, small_cell
+    _with_a_chip(monkeypatch)
+    cell = small_cell(SERVE)
+    line, lines = drive(cell, harness.make_system(cell), traced=True)
+    assert line["correct"], line["checks"]
+    assert not telemetry.TRACER.enabled
+    assert line["attempted"] >= 9 * 4       # whole blocks of a second
+    metrics = line["metrics"]
+    assert "frontend_pct" not in metrics
+    assert "dispatch_host_pct" not in metrics
+    assert 0 <= metrics["serve_queue_pct"]["value"] < 100
+    assert 0 < metrics["device_idle_pct"]["value"] < 100
+    for name in bspans.SHARES:
+        assert 0 < metrics[name]["value"] < 100, name
+    assert set(metrics) <= set(cell.per_layer)
+    assert any(n.startswith(bspans.PREFIX) for n, _ in
+               line["breakdown"]["idle_gaps_by_program_span"])
+    assert "writer_late_s" in lines[0]["client"]

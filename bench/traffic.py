@@ -3,15 +3,24 @@ configuration, and makes the operands and the calls from the seed.
 
 A mix (``bench/traffic/<mix>.json``) holds only parameters:
 
+``path``        the entry point the calls go through: ``"ufunc"`` (the
+                default), one library call each in the harness's own
+                loop; ``"serve"``, one request line each to the batched
+                JSON-lines server (``harness.Served``).
 ``arrivals``    ``"closed"``: one caller, each call sent when the last
                 returned; ``"poisson"``: calls arrive at ``rate_per_s``
-                on average, one caller serves them in order, and a call's
-                latency counts its wait from its arrival.
+                on average, and a call's latency counts its wait from its
+                arrival (on the ``ufunc`` path one caller serves them in
+                order; on the ``serve`` path a writer sends each at its
+                arrival, whatever is still in flight).
 ``rate_per_s``  the offered rate of a ``"poisson"`` mix.
 ``op_counts``   how many calls of each op a block holds (default: one of
                 each of the configuration's ``ops``).
 ``rows``        the row counts a block's calls take, each op at each
                 (default: the configuration's ``rows``).
+``row_counts``  instead of ``rows``: row count -> how many calls of that
+                size a block holds for each call that ``op_counts``
+                names.
 ``order``       ``"rotation"``: a block keeps its ops in turn and the seed
                 picks the op that starts; ``"shuffled"``: the seed orders
                 the block.
@@ -34,6 +43,7 @@ from typing import List, Tuple
 
 import numpy as np
 
+PATHS = ("ufunc", "serve")
 ARRIVALS = ("closed", "poisson")
 ORDERS = ("rotation", "shuffled")
 
@@ -51,6 +61,7 @@ class Traffic:
     pool: List[Tuple[np.ndarray, np.ndarray]]
     open_loop: bool
     row_shards: int
+    path: str = "ufunc"
 
     @property
     def shapes(self) -> List[Tuple[str, int]]:
@@ -114,20 +125,36 @@ def _gaps(rate: float, n: int) -> np.ndarray:
     return -np.log1p(-q) / rate
 
 
+def sizes_of(mix: dict, config: dict) -> List[Tuple[int, int]]:
+    """(rows, calls of that size per op call) of a mix: ``row_counts``, or
+    one call at each of ``rows``."""
+    if "row_counts" in mix:
+        if "rows" in mix:
+            raise ValueError(f"a mix gives rows or row_counts, not both: "
+                             f"{mix}")
+        sizes = [(int(r), int(k)) for r, k in mix["row_counts"].items()]
+    else:
+        sizes = [(int(r), 1) for r in mix.get("rows", [config["rows"]])]
+    if not sizes or min(min(r, k) for r, k in sizes) < 1:
+        raise ValueError(f"bad row counts in mix {mix}")
+    return sizes
+
+
 def make(mix: dict, config: dict, seed: int) -> Traffic:
+    path = mix.get("path", "ufunc")
     arrivals = mix.get("arrivals", "closed")
     order = mix.get("order", "rotation")
-    if arrivals not in ARRIVALS or order not in ORDERS:
-        raise ValueError(f"unknown arrivals or order in mix {mix}")
+    if path not in PATHS or arrivals not in ARRIVALS or order not in ORDERS:
+        raise ValueError(f"unknown path, arrivals or order in mix {mix}")
     rng = rng_for(seed)
     counts = mix.get("op_counts", {op: 1 for op in config["ops"]})
     unknown = set(counts) - set(config["ops"])
     if unknown:
         raise ValueError(f"the mix sends {sorted(unknown)}, which the "
                          f"configuration does not have")
-    sizes = [int(r) for r in mix.get("rows", [config["rows"]])]
+    sizes = sizes_of(mix, config)
     calls = [(op, r) for op, k in counts.items() for _ in range(int(k))
-             for r in sizes]
+             for r, m in sizes for _ in range(m)]
     if order == "rotation":
         start = int(rng.integers(0, len(calls)))
         calls = calls[start:] + calls[:start]
@@ -142,7 +169,8 @@ def make(mix: dict, config: dict, seed: int) -> Traffic:
     else:
         gaps = np.zeros(len(calls))
     pool = int(mix.get("pool", 1))
-    pairs = [operand_pair(rng, config, max(sizes)) for _ in range(pool)]
+    pairs = [operand_pair(rng, config, max(r for r, _ in sizes))
+             for _ in range(pool)]
     block = [Send(op, r, float(g)) for (op, r), g in zip(calls, gaps)]
     return Traffic(block=block, pool=pairs, open_loop=arrivals == "poisson",
-                   row_shards=int(mix["row_shards"]))
+                   row_shards=int(mix["row_shards"]), path=path)
