@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
-"""The control of ``correct``: the plain reference one precision down,
+"""The control of ``correct``: the plain reference one precision down
+(the ``control`` of the cell's family, ``bench/families/<family>.py``),
 put in the program's place, run through the harness at a cell's own
 size.  Every seed has to come out not correct.  On the ``serve`` path
-the control is a server of the same wire format
+the control is a server of the family's wire format
 (:func:`serve_control`), driven by the harness's own client.
 
     python3 bench/control.py --workload <cell> --seconds <s> --seeds <n> ...
@@ -21,50 +22,45 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class Control:
-    """``reference.control`` in the program's place; the plan check
+    """The family's ``control`` in the program's place; the plan check
     still asks the program, so that the control runs where the cell
     does."""
 
     def __init__(self, program):
-        from bench import reference
-        self.ref, self.program = reference, program
+        self.family, self.program = program.family, program
 
-    def call(self, op, x, y):
-        return self.ref.as_result(op, self.ref.control(op, x, y), x.dtype)
+    def call(self, op, *operands):
+        return self.family.control(op, *operands)
 
-    def plan_of(self, op, x, y):
-        return self.program.plan_of(op, x, y)
+    def plan_of(self, op, *operands):
+        return self.program.plan_of(op, *operands)
 
     def counters(self):
         return {}
 
 
-def serve_control(inp, outp) -> None:
+def serve_control(family):
     """A JSON-lines server that answers each request line, in order, with
-    ``reference.control`` of its operands, in the wire format of
-    ``--pim-serve``."""
-    import numpy as np
-    from bench import reference
-    for line in inp:
-        req = json.loads(line)
-        x = np.asarray(req["x"], req["dtype"])
-        y = np.asarray(req["y"], req["dtype"])
-        out = reference.as_result(req["op"],
-                                  reference.control(req["op"], x, y),
-                                  x.dtype)
-        resp = {"op": req["op"], "rows": len(x), "queue_us": 0.0}
-        if isinstance(out, tuple):
-            resp["q"], resp["r"] = (part.tolist() for part in out)
-        else:
-            resp["result"] = out.tolist()
-        print(json.dumps(resp), file=outp, flush=True)
+    the family's ``control`` of its operands, in the family's wire
+    format."""
+    wire = family.WIRE
+
+    def serve(inp, outp) -> None:
+        for line in inp:
+            op, operands = wire.parse(json.loads(line))
+            resp = dict(wire.response(op, operands,
+                                      family.control(op, *operands)),
+                        queue_us=0.0)
+            print(json.dumps(resp), file=outp, flush=True)
+    return serve
 
 
 def control_system(harness, cell):
     """The control in the place of the system the cell's mix names."""
     if cell.mix.get("path", "ufunc") == "serve":
-        return harness.Served(cell.config, serve=serve_control)
-    return Control(harness.Ufuncs(cell.config))
+        return harness.Served(cell.config, cell.family,
+                              serve=serve_control(cell.family))
+    return Control(harness.Ufuncs(cell.config, cell.family))
 
 
 def main(argv=None) -> int:

@@ -4,7 +4,7 @@ A run makes its operands from the seed, warms up every shape the window
 uses (set-up), then either measures a window of whole blocks of the mix
 (``--trace 0``: the end-to-end metrics) or profiles one block
 (``--trace 1``: the per-layer metrics), and checks every result of the
-timed calls against the plain reference (``bench/reference.py``).
+timed calls against the plain reference of the configuration's family.
 
 The mix's ``path`` chooses the entry point: ``ufunc``, one library call
 at a time in the harness's own loop (:class:`Ufuncs`); ``serve``, the
@@ -17,12 +17,44 @@ Everything that belongs to one cell, mix or metric is found by name:
   the metrics it reports;
 * a configuration is the file ``BENCHMARK.json`` gives it;
 * a mix is ``bench/traffic/<mix>.json``, read by ``bench/traffic.py``;
+* a configuration's op family is ``bench/families/<family>.py``, named
+  by the configuration's ``family`` (default ``elementwise``);
 * a metric is ``bench/metrics/<metric>.py``, whose ``read(readings)``
   returns the number or None;
 * the device's peaks are ``bench/peaks.json``, keyed by ``device_kind``.
 
 A metric's reader decides where the metric exists: one that finds
 nothing to read in a run returns None, and the line leaves it out.
+
+Everything that depends on the kind of call lives in the family's file,
+as module-level names; the harness, the generator
+(``bench/traffic.py``) and the control (``bench/control.py``) ask the
+cell's family for them:
+
+``operands(rng, config, rows)``  one operand set, of any shape, covering
+                                 a call of ``rows`` rows
+``head(operands, rows)``         a call's operands for its row count
+``call(lib, op, *operands)``     the untraced call; ``lib`` is the
+                                 :class:`Ufuncs` of the run (``pim``,
+                                 ``kops``, ``kw``)
+``traced_call(lib, span, op, *operands)``  the call with the harness's
+                                 ``bench.prepare|dispatch|finish:<op>``
+                                 spans around its front end, dispatch
+                                 and decode
+``plan_of(lib, op, *operands)``  (row shards, chunk rows) of the call's
+                                 plan: the ``row_shards`` check and the
+                                 warm-up's sizes
+``reference(op, *operands)``     the exact result, independent of the
+                                 program
+``control(op, *operands)``       the reference one precision down, in the
+                                 form ``call`` returns
+``mismatched(op, got, want, rows)``  rows of a call whose result differs
+                                 from the reference's (limit 0)
+``work(op, config)``             ``work.OpWork`` per row, which the
+                                 roofline and gate-rows readers read
+``WIRE``                         the request and response form of the
+                                 ``serve`` path, or None: a cell whose
+                                 mix takes that path is then refused
 """
 
 from __future__ import annotations
@@ -44,11 +76,14 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from . import reference, spans, traffic, work
+from . import spans, traffic, work
 from . import trace as btrace
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
+
+#: The family of a configuration that names none.
+DEFAULT_FAMILY = "elementwise"
 
 #: ``algorithm`` of a configuration -> the ufuncs' ``parallel=``.
 ALGORITHMS = {"bit-serial": False, "bit-parallel": True}
@@ -89,6 +124,7 @@ class Cell:
     chips: int
     config: dict
     mix: dict
+    family: object         # the module of bench/families/<family>.py
     end_to_end: List[str]
     per_layer: List[str]
     units: Dict[str, str]
@@ -110,21 +146,38 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
     config = _read_json(os.path.join(root, configs[w["config"]]["file"]))
     mix = _read_json(os.path.join(root, "bench", "traffic",
                                   w["traffic"] + ".json"))
+    name = config.get("family", DEFAULT_FAMILY)
+    family = load_family(name, root)
+    if mix.get("path", "ufunc") == "serve" and family.WIRE is None:
+        raise BenchError(f"the {name!r} family has no wire form, and the "
+                         f"mix {w['traffic']!r} takes the serve path")
     return Cell(name=workload, chips=int(w["chips"]), config=config, mix=mix,
+                family=family,
                 end_to_end=[m["name"] for m in spec["end_to_end"]],
                 per_layer=[m["name"] for m in spec["per_layer"]],
                 units={m["name"]: m["unit"]
                        for m in spec["end_to_end"] + spec["per_layer"]})
 
 
-def load_metric(name: str) -> Callable:
-    """``read`` of ``bench/metrics/<name>.py``."""
-    path = os.path.join(BENCH, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
+def _load_module(path: str, name: str):
+    if not os.path.isfile(path):
+        raise BenchError(f"no file {path}")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_metric(name: str) -> Callable:
+    """``read`` of ``bench/metrics/<name>.py``."""
+    return _load_module(os.path.join(BENCH, "metrics", name + ".py"),
+                        f"bench_metric_{name}").read
+
+
+def load_family(name: str, root: str = ROOT):
+    """The module of ``bench/families/<name>.py`` under ``root``."""
+    return _load_module(os.path.join(root, "bench", "families",
+                                     name + ".py"), f"bench_family_{name}")
 
 
 def device_peaks(kind: str) -> dict:
@@ -189,35 +242,28 @@ def ufunc_options(config: dict) -> dict:
 
 
 class Ufuncs:
-    """The system under test: the public ufuncs of ``repro.pim_ufunc``,
+    """The system under test: the public functions of ``repro.pim_ufunc``,
     numpy arrays in and out, under the module defaults but for what the
-    configuration sets (:func:`ufunc_options`)."""
+    configuration sets (:func:`ufunc_options`), called as the
+    configuration's ``family`` calls them."""
 
-    def __init__(self, config: dict):
+    def __init__(self, config: dict, family):
         from repro import pim_ufunc
         from repro.kernels import ops
         from repro.runtime import telemetry
         self.pim, self.kops, self.telemetry = pim_ufunc, ops, telemetry
         self.kw = ufunc_options(config)
+        self.family = family
 
-    def call(self, op: str, x, y):
-        return getattr(self.pim, op)(x, y, **self.kw)
+    def call(self, op: str, *operands):
+        return self.family.call(self, op, *operands)
 
-    def traced_call(self, op: str, x, y, span: Callable):
-        """What ``Prepared.run`` does, with a span around each layer."""
-        with span(f"bench.prepare:{op}"):
-            prep = self.pim.prepare(op, x, y, **self.kw)
-        with span(f"bench.dispatch:{op}"):
-            outs = self.kops.run_program_streaming(
-                prep.program, prep.inputs, prep.n_rows, prep.plan)
-        with span(f"bench.finish:{op}"):
-            return prep.finish(outs)
+    def traced_call(self, span: Callable, op: str, *operands):
+        return self.family.traced_call(self, span, op, *operands)
 
-    def plan_of(self, op: str, x, y):
+    def plan_of(self, op: str, *operands):
         """(row shards, chunk rows) of the plan a call of ``op`` takes."""
-        plan = self.pim.prepare(op, x[:1], y[:1], **self.kw).plan
-        shards = 1 if plan.mesh is None else int(plan.mesh.devices.size)
-        return shards, int(plan.effective_chunk_rows)
+        return self.family.plan_of(self, op, *operands)
 
     def counters(self) -> Dict[str, float]:
         snap = self.telemetry.REGISTRY.snapshot()["counters"]
@@ -252,15 +298,17 @@ class Served:
     arrival and a reader thread that stamps each response line with
     ``time.perf_counter()`` as it arrives and keeps its bytes; nothing is
     decoded until the phase has ended.  The server answers in input
-    order, so the ``k``-th response line answers the ``k``-th request."""
+    order, so the ``k``-th response line answers the ``k``-th request.
+    The lines are in the family's wire form (its ``WIRE``)."""
 
-    def __init__(self, config: dict, serve: Optional[Callable] = None):
+    def __init__(self, config: dict, family,
+                 serve: Optional[Callable] = None):
         if serve is None:
             from repro.launch import serve as srv
             serve = functools.partial(srv.serve_pim_batched, **SERVE_OPTIONS)
         self.serve = serve
-        self.lib = Ufuncs(config)
-        self.dtype = config["dtype"]
+        self.lib = Ufuncs(config, family)
+        self.config, self.wire = config, family.WIRE
         self.lines: Dict[tuple, bytes] = {}
         self.answers: List[list] = []       # [arrival, line bytes or None]
         self.sent = 0
@@ -269,8 +317,8 @@ class Served:
         self._threads: List[threading.Thread] = []
         self._req_w: Optional[int] = None
 
-    def plan_of(self, op: str, x, y):
-        return self.lib.plan_of(op, x, y)
+    def plan_of(self, op: str, *operands):
+        return self.lib.plan_of(op, *operands)
 
     def counters(self) -> Dict[str, float]:
         return self.lib.counters()
@@ -282,10 +330,8 @@ class Served:
         server and the reader."""
         for k in range(len(tr.pool)):
             for op, rows in tr.shapes:
-                x, y = tr.pool[k]
-                self.lines[(op, rows, k)] = json.dumps(
-                    {"op": op, "dtype": self.dtype, "x": x[:rows].tolist(),
-                     "y": y[:rows].tolist()}).encode() + b"\n"
+                self.lines[(op, rows, k)] = json.dumps(self.wire.request(
+                    self.config, op, *tr.head(k, rows))).encode() + b"\n"
         req_r, self._req_w = os.pipe()
         resp_r, resp_w = os.pipe()
         _grow_pipe(self._req_w)
@@ -404,10 +450,7 @@ class Served:
             return None
         if "queue_us" in resp:
             c.queue_s = resp["queue_us"] * 1e-6
-        dtype = self.dtype if np.dtype(self.dtype).kind == "f" else np.uint64
-        if "result" in resp:
-            return np.asarray(resp["result"], dtype)
-        return np.asarray(resp["q"], dtype), np.asarray(resp["r"], dtype)
+        return self.wire.result(self.config, resp)
 
     def warm_up(self, tr: traffic.Traffic) -> None:
         """Start the server, then send whole blocks of the mix at its own
@@ -440,8 +483,8 @@ class Served:
 def make_system(cell: Cell):
     """The system under test on the entry point the cell's mix names."""
     if cell.mix.get("path", "ufunc") == "serve":
-        return Served(cell.config)
-    return Ufuncs(cell.config)
+        return Served(cell.config, cell.family)
+    return Ufuncs(cell.config, cell.family)
 
 
 # --------------------------------------------------------------------------
@@ -452,7 +495,7 @@ def make_system(cell: Cell):
 class Call:
     op: str
     rows: int
-    pair: int              # the operand pair it sent
+    pair: int              # the operand set it sent
     seconds: float         # from its arrival to the result in hand
     error: Optional[str] = None
     queue_s: Optional[float] = None     # the server's admission wait
@@ -491,19 +534,18 @@ def warm_up(system, tr: traffic.Traffic) -> None:
     """Run each (op, rows) of the mix once over the head of the operands
     that covers every compiled shape a full call uses: one chunk shape
     when the rows stream (two chunks run), else the whole call."""
-    x, y = tr.pool[0]
     for op, rows in tr.shapes:
-        _, chunk = system.plan_of(op, x, y)
+        _, chunk = system.plan_of(op, *tr.pool[0])
         n = rows if rows <= chunk else min(rows, 2 * chunk)
-        system.call(op, x[:n], y[:n])
+        system.call(op, *tr.head(0, n))
 
 
 def _send(call: Callable, tr: traffic.Traffic, i: int, s: traffic.Send,
           t_arrival: float):
     """The window's ``i``-th call; returns (Call, result or None)."""
-    x, y = tr.operands(i, s)
+    operands = tr.operands(i, s)
     try:
-        out, err = call(s.op, x, y), None
+        out, err = call(s.op, *operands), None
     except Exception as exc:                    # a failed call, counted
         out, err = None, f"{type(exc).__name__}: {exc}"
     return Call(s.op, s.rows, tr.pair(i), time.perf_counter() - t_arrival,
@@ -566,8 +608,8 @@ def run_traced(jax, system, tr: traffic.Traffic):
     def ufunc_block(tr):
         calls, results = [], []
 
-        def call(op, x, y):
-            return system.traced_call(op, x, y, span)
+        def call(op, *operands):
+            return system.traced_call(span, op, *operands)
         arrivals = _arrivals(tr, time.perf_counter())
         for _ in tr.block:
             c, out = _send(call, tr, *next(arrivals))
@@ -596,9 +638,10 @@ def run_traced(jax, system, tr: traffic.Traffic):
 
 def check(calls: List[Call], results: list,
           tr: traffic.Traffic) -> Dict[str, dict]:
-    """Mismatched rows of every timed call against the reference for its
-    own operands, summed per op; a call that raised counts all its rows.
-    Limit 0: the configuration's guarantee is bit-exact."""
+    """Mismatched rows of every timed call against the family's reference
+    for its own operands, summed per op; a call that raised counts all
+    its rows.  Limit 0: the configuration's guarantee is exact."""
+    family = tr.family
     wants, bad = {}, {op: 0 for op, _ in tr.shapes}
     for i, (c, out) in enumerate(zip(calls, results)):
         if out is None:
@@ -606,9 +649,8 @@ def check(calls: List[Call], results: list,
             continue
         key = (c.op, c.pair, c.rows)
         if key not in wants:
-            x, y = tr.pool[c.pair]
-            wants[key] = reference.reference(c.op, x[:c.rows], y[:c.rows])
-        n_bad = reference.mismatched_rows(c.op, out, wants[key])
+            wants[key] = family.reference(c.op, *tr.head(c.pair, c.rows))
+        n_bad = family.mismatched(c.op, out, wants[key], c.rows)
         bad[c.op] += n_bad
         if n_bad:
             c.error = c.error or f"{n_bad} rows differ from the reference"
@@ -645,7 +687,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     """One run of ``cell``; returns the result line (a dict)."""
     kind = devices[0].device_kind
     peaks = device_peaks(kind)
-    tr = traffic.make(cell.mix, cell.config, seed)
+    tr = traffic.make(cell.mix, cell.config, seed, cell.family)
     shards, _ = system.plan_of(tr.block[0].op, *tr.pool[0])
     if shards != tr.row_shards:
         raise BenchError(f"the mix asks for rows over {tr.row_shards} "
@@ -701,8 +743,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     if served:
         checks["extra_responses"] = {"value": extra, "limit": 0}
     readings = Readings(setup_s=setup_s, calls=calls, window_s=window_s,
-                        work={op: work.op_work(op, cell.config["dtype"],
-                                               cell.config["algorithm"])
+                        work={op: cell.family.work(op, cell.config)
                               for op, _ in tr.shapes},
                         peaks=peaks, trace=reduced, spans=program)
     metrics = {}

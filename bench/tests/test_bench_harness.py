@@ -16,7 +16,7 @@ import jax
 import numpy as np
 import pytest
 
-from bench import control, harness, reference, traffic, work
+from bench import control, harness, traffic
 from bench import spans as bspans
 from bench import trace as btrace
 from repro.kernels import ops as kops
@@ -27,6 +27,8 @@ UFUNC_CELLS = ["fp32-fig9-64Mi", "int32-fig9-4Mi", "fp32-fig9-64Mi-x4"]
 SERVE = "fp32-serve-open"
 #: The serve mix at a size and rate the CPU holds: a block is 9 requests.
 SMALL_SERVE = {"row_counts": {"64": 2, "256": 1}, "rate_per_s": 400.0}
+#: The family of every committed configuration.
+EW = harness.load_family("elementwise")
 
 
 @dataclasses.dataclass
@@ -52,6 +54,7 @@ def serve_cell() -> harness.Cell:
     units = {m["name"]: m["unit"]
              for m in spec["end_to_end"] + spec["per_layer"]}
     return harness.Cell(name=SERVE, chips=1, config=config, mix=mix,
+                        family=EW,
                         end_to_end=[m["name"] for m in spec["end_to_end"]],
                         per_layer=per_layer,
                         units=dict(units, serve_queue_pct="%"))
@@ -215,8 +218,8 @@ def test_a_run_on_the_cpu_is_correct(name):
 
 def test_seed_fixes_operands_and_order():
     cell = small_cell("int32-fig9-4Mi")
-    a = traffic.make(cell.mix, cell.config, 3 * 2**31)
-    b = traffic.make(cell.mix, cell.config, 3 * 2**31)
+    a = traffic.make(cell.mix, cell.config, 3 * 2**31, EW)
+    b = traffic.make(cell.mix, cell.config, 3 * 2**31, EW)
     assert a.block == b.block
     assert sorted(s.op for s in a.block) == sorted(cell.config["ops"])
     for (xa, ya), (xb, yb) in zip(a.pool, b.pool):
@@ -229,8 +232,8 @@ def test_seed_fixes_operands_and_order():
     assert a.path == "ufunc"
     # the serve mix as committed: 63 requests of 64,512 rows a block
     serve = serve_cell()
-    a = traffic.make(serve.mix, serve.config, 3 * 2**31)
-    b = traffic.make(serve.mix, serve.config, 3 * 2**31)
+    a = traffic.make(serve.mix, serve.config, 3 * 2**31, EW)
+    b = traffic.make(serve.mix, serve.config, 3 * 2**31, EW)
     assert a.block == b.block and a.path == "serve" and a.open_loop
     for (xa, ya), (xb, yb) in zip(a.pool, b.pool):
         assert np.array_equal(xa, xb) and np.array_equal(ya, yb)
@@ -246,7 +249,8 @@ def test_every_seed_sends_the_same_work_in_its_own_order():
            "op_counts": {"add": 3, "mul": 2, "div": 1},
            "rows": [64, 1024], "pool": 3, "row_shards": 1}
     config = harness.load_cell("int32-fig9-4Mi").config
-    blocks = [traffic.make(mix, config, seed).block for seed in (1, 2**40)]
+    blocks = [traffic.make(mix, config, seed, EW).block
+              for seed in (1, 2**40)]
     assert blocks[0] != blocks[1]
     for key in (lambda s: (s.op, s.rows), lambda s: s.gap_s):
         assert sorted(map(key, blocks[0])) == sorted(map(key, blocks[1]))
@@ -260,11 +264,11 @@ def test_every_seed_sends_the_same_work_in_its_own_order():
                 {"rows": None, "row_counts": {"64": 0}}):
         with pytest.raises(ValueError):
             traffic.make({k: v for k, v in dict(mix, **bad).items()
-                          if v is not None}, config, 1)
+                          if v is not None}, config, 1, EW)
     # sizes by count: each op call of op_counts at each size, that often
     counted = dict(mix, row_counts={"64": 3, "1024": 1})
     del counted["rows"]
-    blocks = [traffic.make(counted, config, seed).block
+    blocks = [traffic.make(counted, config, seed, EW).block
               for seed in (1, 2**40)]
     assert blocks[0] != blocks[1] and len(blocks[0]) == 24
     for key in (lambda s: (s.op, s.rows), lambda s: s.gap_s):
@@ -277,7 +281,7 @@ def test_an_open_loop_counts_the_wait_from_arrival():
     cell = small_cell("int32-fig9-4Mi", arrivals="poisson",
                       rate_per_s=1000.0, op_counts={"add": 2, "mul": 1},
                       rows=[256, ROWS])
-    line, lines = drive(cell, harness.Ufuncs(cell.config))
+    line, lines = drive(cell, harness.Ufuncs(cell.config, EW))
     assert line["correct"], line["checks"]
     assert line["attempted"] % 6 == 0
     assert lines[0]["window"]["compiles"] == 0
@@ -295,10 +299,12 @@ def test_fp_operands_and_control_for_any_ieee_format(dtype, keep):
     assert np.all(np.abs(x) < 2.0**6)
     drop = np.finfo(dtype).nmant - keep
     for op in ("fp_add", "fp_mul", "fp_div"):
-        want = reference.reference(op, x, y)
-        low = reference.control(op, x, y)
-        assert np.all(low.astype(np.uint64) % (1 << drop) == 0)
-        assert reference.mismatched_rows(op, want, low) > 3000
+        want = EW.reference(op, x, y)
+        low = EW.control(op, x, y)
+        assert low.dtype == x.dtype             # as the ufunc returns it
+        assert np.all(low.view(want.dtype).astype(np.uint64)
+                      % (1 << drop) == 0)
+        assert EW.mismatched(op, low, want, len(x)) > 3000
     with pytest.raises(ValueError, match="normal range"):
         traffic.fp_operands(rng, "float16", 4, -20, 5)
 
@@ -310,8 +316,8 @@ def test_the_configuration_sets_the_algorithm():
         == {"parallel": True}
     with pytest.raises(harness.BenchError, match="unknown algorithm"):
         harness.ufunc_options(dict(config, algorithm="bit-sliced"))
-    serial = work.op_work("fp_add", "float32", "bit-serial")
-    parallel = work.op_work("fp_add", "float32", "bit-parallel")
+    serial = EW.work("fp_add", config)
+    parallel = EW.work("fp_add", dict(config, algorithm="bit-parallel"))
     assert serial.nor_gates != parallel.nor_gates
     assert (serial.in_bits, serial.out_bits) == \
         (parallel.in_bits, parallel.out_bits)
@@ -320,8 +326,8 @@ def test_the_configuration_sets_the_algorithm():
 def test_a_bit_parallel_configuration_runs_bit_parallel():
     cell = small_cell("fp32-fig9-64Mi")
     cell.config = dict(cell.config, algorithm="bit-parallel", rows=256)
-    system = harness.Ufuncs(cell.config)
-    tr = traffic.make(cell.mix, cell.config, 9)
+    system = harness.Ufuncs(cell.config, EW)
+    tr = traffic.make(cell.mix, cell.config, 9, EW)
     from repro.core.pim_numerics import program_for
     x, y = tr.pool[0]
     prep = system.pim.prepare("fp_mul", x, y, **system.kw)
@@ -384,8 +390,8 @@ def _state_unchanged(monkeypatch):
                               "level_loop_skipped"])
 def test_a_broken_timed_path_is_not_correct(monkeypatch, name, fault):
     cell = small_cell(name)
-    system = harness.Ufuncs(cell.config)
-    harness.warm_up(system, traffic.make(cell.mix, cell.config, 1))
+    system = harness.Ufuncs(cell.config, EW)
+    harness.warm_up(system, traffic.make(cell.mix, cell.config, 1, EW))
     fault(monkeypatch)                          # compiled before breaking
     line, _ = drive(cell, system)
     assert not line["correct"]
@@ -399,14 +405,10 @@ def _reference_server(stall_s: float = 0.0):
     def serve(inp, outp):
         time.sleep(stall_s)
         for line in inp:
-            req = json.loads(line)
-            x = np.asarray(req["x"], req["dtype"])
-            y = np.asarray(req["y"], req["dtype"])
-            out = reference.as_result(
-                req["op"], reference.reference(req["op"], x, y), x.dtype)
-            print(json.dumps({"op": req["op"], "rows": len(x),
-                              "queue_us": 0.0, "result": out.tolist()}),
-                  file=outp, flush=True)
+            op, (x, y) = EW.WIRE.parse(json.loads(line))
+            out = EW.as_result(op, EW.reference(op, x, y), x.dtype)
+            print(json.dumps(dict(EW.WIRE.response(op, (x, y), out),
+                                  queue_us=0.0)), file=outp, flush=True)
     return serve
 
 
@@ -416,9 +418,9 @@ def test_the_served_warm_up_sends_blocks_until_one_compiles_nothing(
     mix's rate and stops after the first block that adds no
     ``pim.jit.compiles``, or after :data:`harness.WARMUP_BLOCKS`."""
     cell = small_cell(SERVE)
-    tr = traffic.make(cell.mix, cell.config, 2**31 + 11)
+    tr = traffic.make(cell.mix, cell.config, 2**31 + 11, EW)
     for compiling, blocks in ((2, 3), (99, harness.WARMUP_BLOCKS)):
-        system = harness.Served(cell.config, serve=_reference_server())
+        system = harness.Served(cell.config, EW, serve=_reference_server())
         sent = []
 
         def counters(system=system, compiling=compiling):
@@ -441,9 +443,9 @@ def test_a_stalled_server_counts_the_wait_from_arrival():
     cell = small_cell(SERVE, row_counts={"64": 2, "16384": 1},
                       rate_per_s=200.0)
     config = dict(cell.config, rows=16384)
-    tr = traffic.make(cell.mix, config, 2**33 + 3)
+    tr = traffic.make(cell.mix, config, 2**33 + 3, EW)
     stall = 0.5
-    system = harness.Served(config, serve=_reference_server(stall))
+    system = harness.Served(config, EW, serve=_reference_server(stall))
     system.start(tr)
     t0 = time.perf_counter()
     calls, results, window_s = system.send(tr, 2)
@@ -556,7 +558,7 @@ def test_a_broken_served_path_is_not_correct(monkeypatch, fault):
     monkeypatch.setattr(harness, "WARMUP_BLOCKS", 2)
     cell = small_cell(SERVE)
     serve = fault(monkeypatch)
-    line, _ = drive(cell, harness.Served(cell.config, serve=serve))
+    line, _ = drive(cell, harness.Served(cell.config, EW, serve=serve))
     assert not line["correct"]
     assert line["failed"] > 0
 
@@ -566,16 +568,16 @@ def test_reference_and_control_differ_as_stated():
     x = traffic.fp_operands(rng, "float32", 4096, -4, 5)
     y = traffic.fp_operands(rng, "float32", 4096, -4, 5)
     for op in ("fp_add", "fp_mul", "fp_div"):
-        want = reference.reference(op, x, y)
-        low = reference.control(op, x, y)
+        want = EW.reference(op, x, y)
+        low = EW.control(op, x, y).view(np.uint32)
         assert np.all(low & 0xFFFF == 0)
-        assert reference.mismatched_rows(op, want, low) > 4000
+        assert EW.mismatched(op, want, low, len(x)) > 4000
     xi = traffic.int_operands(rng, "uint32", 4096, 0)
     yi = traffic.int_operands(rng, "uint32", 4096, 1)
-    assert reference.mismatched_rows(
-        "add", reference.reference("add", xi, yi),
-        reference.control("add", xi, yi)) > 1000
-    q, r = reference.reference("div", xi, yi)
+    assert EW.mismatched(
+        "add", EW.reference("add", xi, yi),
+        EW.control("add", xi, yi), len(xi)) > 1000
+    q, r = EW.reference("div", xi, yi)
     assert np.array_equal(q * yi + r, xi.astype(np.uint64))
 
 
@@ -593,7 +595,8 @@ with open(os.path.join(harness.BENCH, "traffic", "closed-rotation-x4.json")) as 
     cell.mix = json.load(f)
 line = harness.run_cell(cell, 2**32 + 5, 0.05, False, jax=jax,
                         devices=[FakeDevice(id=i) for i in range(4)],
-                        system=harness.Ufuncs(cell.config), t_process=0.0,
+                        system=harness.Ufuncs(cell.config, cell.family),
+                        t_process=0.0,
                         emit=lambda d: None)
 print(json.dumps(line))
 """

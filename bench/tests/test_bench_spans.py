@@ -190,7 +190,8 @@ def test_a_traced_run_of_the_harness_leaves_the_tracer_off(name,
     paths = _with_a_chip(monkeypatch)
     cell = small_cell(name)
     telemetry.TRACER.drain()
-    line, _ = drive(cell, harness.Ufuncs(cell.config), traced=True)
+    line, _ = drive(cell, harness.Ufuncs(cell.config, cell.family),
+                    traced=True)
     assert line["correct"], line["checks"]
     assert not telemetry.TRACER.enabled
     assert telemetry.TRACER.drain() == []

@@ -8,8 +8,10 @@ import os
 import jax
 import pytest
 
-from bench import harness, work
+from bench import harness
 from bench import trace as btrace
+
+EW = harness.load_family("elementwise")
 
 FIG9 = {  # op, dtype -> NOR gates, in bits, out bits
     ("add", "uint32"): (352, 64, 33),
@@ -23,7 +25,7 @@ FIG9 = {  # op, dtype -> NOR gates, in bits, out bits
 
 @pytest.mark.parametrize("op,dtype", sorted(FIG9))
 def test_fig9_work(op, dtype):
-    w = work.op_work(op, dtype, "bit-serial")
+    w = EW.op_work(op, dtype, "bit-serial")
     assert (w.nor_gates, w.in_bits, w.out_bits) == FIG9[op, dtype]
     assert w.port_bytes == (w.in_bits + w.out_bits) / 8
 
@@ -81,7 +83,7 @@ def test_reduce_synthetic():
 
 def test_readers_on_synthetic_trace():
     r = btrace.reduce(synthetic())
-    w = work.op_work("fp_add", "float32", "bit-serial")
+    w = EW.op_work("fp_add", "float32", "bit-serial")
     readings = harness.Readings(
         setup_s=1.0, calls=[harness.Call("fp_add", 1000, 0, 0.1)],
         window_s=r.window_s, work={"fp_add": w},

@@ -24,16 +24,21 @@ A mix (``bench/traffic/<mix>.json``) holds only parameters:
 ``order``       ``"rotation"``: a block keeps its ops in turn and the seed
                 picks the op that starts; ``"shuffled"``: the seed orders
                 the block.
-``pool``        how many distinct operand pairs the run draws; the
-                window's ``i``-th call takes pair ``i % pool``, so no two
+``pool``        how many distinct operand sets the run draws; the
+                window's ``i``-th call takes set ``i % pool``, so no two
                 calls in a row send the same data.
 ``row_shards``  how many chips the default plan must spread each call's
                 rows over (the harness refuses a run where it does not).
 
 A block is the unit of work: every seed sends the same calls, sizes and
 arrival gaps in a block, in its own order.  The window runs whole blocks.
-The operands come from the configuration's ``dtype`` and ``operands``
-group; the same seed gives the same operands and the same calls.
+The same seed gives the same operands and the same calls.
+
+What an operand set is, and what a call of so many rows takes of it, is
+the configuration's family's to say (``bench/families/<family>.py``:
+``operands`` draws one set from the run's generator, ``head`` cuts a
+call's operands from it); the value generators here
+(:func:`fp_operands`, :func:`int_operands`) serve every family.
 """
 
 from __future__ import annotations
@@ -58,9 +63,10 @@ class Send:
 @dataclasses.dataclass
 class Traffic:
     block: List[Send]      # one block, in the order it is sent
-    pool: List[Tuple[np.ndarray, np.ndarray]]
+    pool: List[tuple]      # operand sets, each from the family's operands
     open_loop: bool
     row_shards: int
+    family: object         # the configuration's family module
     path: str = "ufunc"
 
     @property
@@ -69,12 +75,16 @@ class Traffic:
         return sorted({(s.op, s.rows) for s in self.block})
 
     def pair(self, i: int) -> int:
-        """The operand pair of the window's ``i``-th call."""
+        """The operand set of the window's ``i``-th call."""
         return i % len(self.pool)
 
-    def operands(self, i: int, s: Send):
-        x, y = self.pool[self.pair(i)]
-        return x[:s.rows], y[:s.rows]
+    def head(self, pair: int, rows: int) -> tuple:
+        """The operands of a call of ``rows`` rows on pool set ``pair``."""
+        return self.family.head(self.pool[pair], rows)
+
+    def operands(self, i: int, s: Send) -> tuple:
+        """The operands of the window's ``i``-th call."""
+        return self.head(self.pair(i), s.rows)
 
 
 def rng_for(seed: int) -> np.random.Generator:
@@ -109,15 +119,6 @@ def int_operands(rng, dtype: str, n: int, low: int):
                         ).astype(dt)
 
 
-def operand_pair(rng, config: dict, n: int):
-    dtype, spec = config["dtype"], config["operands"]
-    if np.dtype(dtype).kind == "f":
-        return tuple(fp_operands(rng, dtype, n, spec["exp_min"],
-                                 spec["exp_max"]) for _ in range(2))
-    return (int_operands(rng, dtype, n, spec["x_min"]),
-            int_operands(rng, dtype, n, spec["y_min"]))
-
-
 def _gaps(rate: float, n: int) -> np.ndarray:
     """``n`` exponential gaps of mean ``1/rate``: the quantiles at the
     middle of ``n`` equal steps, so that every seed gets the same gaps."""
@@ -140,7 +141,10 @@ def sizes_of(mix: dict, config: dict) -> List[Tuple[int, int]]:
     return sizes
 
 
-def make(mix: dict, config: dict, seed: int) -> Traffic:
+def make(mix: dict, config: dict, seed: int, family) -> Traffic:
+    """The run's traffic: the block of calls, and ``pool`` operand sets
+    of ``family`` (a module of ``bench/families``), each covering the
+    mix's largest call."""
     path = mix.get("path", "ufunc")
     arrivals = mix.get("arrivals", "closed")
     order = mix.get("order", "rotation")
@@ -169,8 +173,9 @@ def make(mix: dict, config: dict, seed: int) -> Traffic:
     else:
         gaps = np.zeros(len(calls))
     pool = int(mix.get("pool", 1))
-    pairs = [operand_pair(rng, config, max(r for r, _ in sizes))
-             for _ in range(pool)]
+    sets = [family.operands(rng, config, max(r for r, _ in sizes))
+            for _ in range(pool)]
     block = [Send(op, r, float(g)) for (op, r), g in zip(calls, gaps)]
-    return Traffic(block=block, pool=pairs, open_loop=arrivals == "poisson",
-                   row_shards=int(mix["row_shards"]), path=path)
+    return Traffic(block=block, pool=sets, open_loop=arrivals == "poisson",
+                   row_shards=int(mix["row_shards"]), family=family,
+                   path=path)
